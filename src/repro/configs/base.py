@@ -76,7 +76,8 @@ class ArchConfig:
     # numerics / runtime -------------------------------------------------------
     dtype: str = "bfloat16"          # activation/compute dtype
     param_dtype: str = "float32"
-    use_kernels: bool = False        # dispatch to pallas interpret kernels
+    use_kernels: bool = False        # Pallas kernels via kernels/ops (Mosaic
+                                     # on TPU, interpret mode elsewhere)
     fused_attention: bool = False    # chunked online-softmax attention (no
                                      # S^2 materialisation; pallas on TPU)
     attn_chunk: int = 1024           # kv-chunk for fused attention

@@ -20,6 +20,7 @@ import numpy as np
 from ..checkpoint.store import ArtifactStore
 from ..configs.base import ArchConfig
 from ..data import tokens as token_data
+from ..launch import shardings as launch_shardings
 from ..models import lenet, lm, sharding as msh, steps
 from ..optim import adamw
 from ..optim.schedules import warmup_cosine
@@ -113,18 +114,29 @@ class LMTrainJob:
                 params, grads, opt_state, opt_cfg, lr_scale=schedule(step_i))
             return params, opt_state, {"loss": loss, **metrics, **om}
 
-        ctx = msh.use_mesh(self.mesh) if self.mesh is not None else msh.use_mesh(None)
-        with ctx:
+        def init(key):
+            params = lm.init_params(key, cfg)
+            return params, adamw.init_opt_state(params)
+
+        state_sh = None
+        if self.mesh is not None:
+            # params AND optimizer state are made in place on their shards,
+            # never whole on one device first
+            p_spec = steps.params_spec(cfg)
+            state_sh = (msh.param_shardings(p_spec, self.mesh),
+                        launch_shardings.opt_shardings(
+                            steps.opt_state_spec(p_spec), p_spec, self.mesh,
+                            zero1=cfg.zero1))
+        with msh.use_mesh(self.mesh):
             with self.log.stage("tfjob:init"):
-                params = lm.init_params(jax.random.PRNGKey(self.seed), cfg)
-                opt = adamw.init_opt_state(params)
+                params, opt = jax.jit(init, out_shardings=state_sh)(
+                    jax.random.PRNGKey(self.seed))
                 if resume_from and self.store:
                     params = self.store.load_tree(resume_from, params)
                     if self.store.exists(f"{resume_from}_opt"):
                         opt = self.store.load_tree(f"{resume_from}_opt", opt)
-                if self.mesh is not None:
-                    shardings = msh.param_shardings(params, self.mesh)
-                    params = jax.device_put(params, shardings)
+                    if state_sh is not None:
+                        params, opt = jax.device_put((params, opt), state_sh)
                 jstep = jax.jit(train_step, donate_argnums=(0, 1))
             data = token_data.lm_batches(cfg, self.batch_size, self.seq_len,
                                          seed=self.seed)

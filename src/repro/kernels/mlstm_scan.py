@@ -28,37 +28,49 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, y_ref,
         n_ref[...] = jnp.zeros_like(n_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG)
 
-    qs = q_ref[0, :, 0, :].astype(jnp.float32) * (d ** -0.5)      # (T,D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    li = li_ref[0, :, 0].astype(jnp.float32)                      # (T,)
-    lf = lf_ref[0, :, 0].astype(jnp.float32)
-    C, nv, m = c_ref[...], n_ref[0], m_ref[0, 0]
+    qs = q_ref[...].astype(jnp.float32) * (d ** -0.5)            # (T,D)
+    k = k_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
+    li = li_ref[...].astype(jnp.float32)                          # (1,T)
+    lf = lf_ref[...].astype(jnp.float32)
+    C, nv, m = c_ref[...], n_ref[...], m_ref[...]                 # (D,D),(1,D),(1,1)
 
-    bcum = jnp.cumsum(lf)                                         # (T,)
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    wlog = jnp.where(t_idx >= s_idx,
-                     bcum[:, None] - bcum[None, :] + li[None, :], NEG)
-    glog = bcum + m                                               # (T,)
-    m_row = jnp.maximum(jnp.max(wlog, axis=1), glog)
-    wexp = jnp.exp(wlog - m_row[:, None])
+    causal, diag = t_idx >= s_idx, t_idx == s_idx
+    # cumsum of the log forget gates as masked reductions (exact f32 adds):
+    # (T,1) columns index rows t, (1,T) rows index columns s
+    bcum = jnp.sum(jnp.where(causal, lf, 0.0), axis=1, keepdims=True)      # (T,1)
+    bcum_row = jnp.sum(jnp.where(diag, bcum, 0.0), axis=0, keepdims=True)  # (1,T)
+    li_col = jnp.sum(jnp.where(diag, li, 0.0), axis=1, keepdims=True)      # (T,1)
+    b_last = jnp.sum(lf, axis=1, keepdims=True)                            # (1,1)
+    wlog = jnp.where(causal, bcum - bcum_row + li, NEG)           # (T,T)
+    glog = bcum + m                                               # (T,1)
+    m_row = jnp.maximum(jnp.max(wlog, axis=1, keepdims=True), glog)
+    wexp = jnp.exp(wlog - m_row)
     gexp = jnp.exp(glog - m_row)
 
-    scores = (qs @ k.T) * wexp                                    # (T,T)
+    scores = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ()))) * wexp  # (T,T)
     y_intra = scores @ v
-    y_state = gexp[:, None] * (qs @ C.T)                          # C[d,e]: q over e
-    nq = jnp.sum(scores, axis=1) + gexp * (qs @ nv)
+    y_state = gexp * jax.lax.dot_general(
+        qs, C, (((1,), (1,)), ((), ())))                          # C[d,e]: q over e
+    nq = jnp.sum(scores, axis=1, keepdims=True) \
+        + gexp * jnp.sum(qs * nv, axis=1, keepdims=True)          # (T,1)
     denom = jnp.maximum(jnp.abs(nq), jnp.exp(-m_row))
-    y_ref[0, :, 0, :] = ((y_intra + y_state) / denom[:, None]).astype(y_ref.dtype)
+    y_ref[...] = ((y_intra + y_state) / denom).astype(y_ref.dtype)
 
     # carry update, restabilised at m_new
-    m_new = jnp.maximum(bcum[-1] + m, jnp.max(li + (bcum[-1] - bcum)))
-    c_decay = jnp.exp(bcum[-1] + m - m_new)
-    inj = jnp.exp(li + (bcum[-1] - bcum) - m_new)                 # (T,)
-    c_ref[...] = C * c_decay + (v * inj[:, None]).T @ k           # (D,D)
-    n_ref[0] = nv * c_decay + jnp.sum(k * inj[:, None], axis=0)
-    m_ref[0, 0] = m_new
+    m_new = jnp.maximum(b_last + m, jnp.max(li + (b_last - bcum_row), axis=1,
+                                            keepdims=True))
+    c_decay = jnp.exp(b_last + m - m_new)                         # (1,1)
+    inj = jnp.exp(li_col + (b_last - bcum) - m_new)               # (T,1)
+    # Mosaic cannot broadcast (1,1) over sublanes and lanes in one step:
+    # widen to a (D,1) column through a select, which is not folded away
+    rows = jax.lax.broadcasted_iota(jnp.int32, (d, 1), 0)
+    c_decay_col = jnp.where(rows >= 0, c_decay, 0.0)              # (D,1)
+    c_ref[...] = C * c_decay_col + (v * inj).T @ k
+    n_ref[...] = nv * c_decay + jnp.sum(k * inj, axis=0, keepdims=True)
+    m_ref[...] = m_new
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -73,18 +85,17 @@ def mlstm_scan(q, k, v, logi, logf, *, chunk: int = 128, interpret: bool = True)
         logi = jnp.pad(logi, ((0, 0), (0, pad), (0, 0)), constant_values=NEG)
         logf = jnp.pad(logf, ((0, 0), (0, pad), (0, 0)))
     nc = q.shape[1] // t
+    # head-major, so that each grid step's tiles are (T, D) and (1, T)
+    q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))      # (B,H,S,D)
+    logi, logf = (a.transpose(0, 2, 1)[:, :, None, :] for a in (logi, logf))
     kernel = functools.partial(_mlstm_kernel, chunk=t, d=d)
+    tile = pl.BlockSpec((None, None, t, d), lambda ib, ih, ic: (ib, ih, ic, 0))
+    gate = pl.BlockSpec((None, None, 1, t), lambda ib, ih, ic: (ib, ih, 0, ic))
     y = pl.pallas_call(
         kernel,
         grid=(b, h, nc),
-        in_specs=[
-            pl.BlockSpec((1, t, 1, d), lambda ib, ih, ic: (ib, ic, ih, 0)),
-            pl.BlockSpec((1, t, 1, d), lambda ib, ih, ic: (ib, ic, ih, 0)),
-            pl.BlockSpec((1, t, 1, d), lambda ib, ih, ic: (ib, ic, ih, 0)),
-            pl.BlockSpec((1, t, 1), lambda ib, ih, ic: (ib, ic, ih)),
-            pl.BlockSpec((1, t, 1), lambda ib, ih, ic: (ib, ic, ih)),
-        ],
-        out_specs=pl.BlockSpec((1, t, 1, d), lambda ib, ih, ic: (ib, ic, ih, 0)),
+        in_specs=[tile, tile, tile, gate, gate],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((d, d), jnp.float32),
@@ -92,5 +103,5 @@ def mlstm_scan(q, k, v, logi, logf, *, chunk: int = 128, interpret: bool = True)
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, logi, logf)
+    )(q, k, v, logi, logf).transpose(0, 2, 1, 3)
     return y[:, :s] if pad else y

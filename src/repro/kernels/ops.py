@@ -1,11 +1,12 @@
-"""Jit'd dispatch layer: Pallas kernel on TPU (or interpret-mode when asked),
-pure-jnp reference otherwise.
+"""Jit'd dispatch layer: Pallas kernel or pure-jnp reference.
 
 Model code calls these entry points only; ``use_kernel`` comes from
-ArchConfig.use_kernels.  On this CPU container interpret=True executes the
-kernel body in Python (slow) -- tests use it for correctness sweeps, while
-smoke tests / benchmarks default to the jnp reference path.  On a real TPU
-``interpret=False`` compiles the same kernels to Mosaic.
+ArchConfig.use_kernels, which no config turns on, so the served path runs
+the jnp references.  With ``use_kernel=True`` a kernel compiles to Mosaic on
+a TPU backend and runs in interpret mode (the kernel body executed op by op,
+slowly) on any other backend, which is how the CPU tests sweep it for
+correctness.  tests/test_tpu_compile.py compiles every kernel for a
+described TPU v5e at real widths.
 """
 from __future__ import annotations
 

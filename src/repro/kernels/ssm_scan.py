@@ -22,39 +22,47 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *,
                 chunk: int, n_chunks: int):
+    ih = pl.program_id(1)
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (T, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # (T,)
-    a = a_ref[0]                                     # scalar decay rate (<0)
-    bm = b_ref[0].astype(jnp.float32)                # (T, N)
-    cm = c_ref[0].astype(jnp.float32)                # (T, N)
+    x = x_ref[...].astype(jnp.float32)               # (T, P)
+    dt_row = dt_ref[...].astype(jnp.float32)         # (1, T)
+    a = a_ref[ih]                                    # scalar decay rate (<0)
+    bm = b_ref[...].astype(jnp.float32)              # (T, N)
+    cm = c_ref[...].astype(jnp.float32)              # (T, N)
     h = h_ref[...]                                   # (P, N) f32 carry
 
-    log_a = a * dt                                   # (T,)
-    cum = jnp.cumsum(log_a)                          # (T,)
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal, diag = t_idx >= s_idx, t_idx == s_idx
+    # cumsum as masked reductions (exact f32 adds), in both orientations:
+    # a (T,1) column indexes rows t, a (1,T) row indexes columns s
+    log_a = a * dt_row                                            # (1, T)
+    cum = jnp.sum(jnp.where(causal, log_a, 0.0), axis=1, keepdims=True)   # (T, 1)
+    cum_row = jnp.sum(jnp.where(diag, cum, 0.0), axis=0, keepdims=True)   # (1, T)
+    dt_col = jnp.sum(jnp.where(diag, dt_row, 0.0), axis=1, keepdims=True)  # (T, 1)
+    cum_last = jnp.sum(log_a)
     # L[t,s] = exp(cum_t - cum_s) for s<=t else 0 (mask exponent pre-exp to
     # avoid overflow in the dead upper triangle)
-    L = jnp.exp(jnp.where(t_idx >= s_idx, cum[:, None] - cum[None, :], -1e30))
-    G = cm @ bm.T                                    # (T, T)
-    M = G * L * dt[None, :]
-    y_intra = M @ x                                  # (T, P)
-    y_state = jnp.exp(cum)[:, None] * (cm @ h.T)     # (T, P)
-    y_ref[0, :, 0, :] = (y_intra + y_state).astype(y_ref.dtype)
+    L = jnp.exp(jnp.where(causal, cum - cum_row, -1e30))
+    G = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())))    # (T, T)
+    M = G * L * dt_row
+    y_intra = M @ x                                              # (T, P)
+    y_state = jnp.exp(cum) * jax.lax.dot_general(
+        cm, h, (((1,), (1,)), ((), ())))                         # (T, P)
+    y_ref[...] = (y_intra + y_state).astype(y_ref.dtype)
 
-    w = dt * jnp.exp(cum[-1] - cum)                  # (T,)
-    h_new = h * jnp.exp(cum[-1]) + jnp.einsum("tp,tn->pn", x * w[:, None], bm)
+    w = dt_col * jnp.exp(cum_last - cum)                         # (T, 1)
+    h_new = h * jnp.exp(cum_last) + (x * w).T @ bm               # (P, N)
     h_ref[...] = h_new
 
     @pl.when(ic == n_chunks - 1)
     def _emit_state():
-        hout_ref[0, 0] = h_new.astype(hout_ref.dtype)
+        hout_ref[...] = h_new.astype(hout_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -75,28 +83,33 @@ def ssm_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0)))
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
     nc = x.shape[1] // t
+    # head-major: each grid step's tiles are (T, P) and (1, T), which Mosaic
+    # accepts; the model's (B,S,H,P) layout would give (1, P) head slices
+    xt = x.transpose(0, 2, 1, 3)                      # (B,H,S,P)
+    dtt = dt.transpose(0, 2, 1)[:, :, None, :]        # (B,H,1,S)
     kernel = functools.partial(_ssd_kernel, chunk=t, n_chunks=nc)
     y, h_final = pl.pallas_call(
         kernel,
         grid=(b, h, nc),
         in_specs=[
-            pl.BlockSpec((1, t, 1, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
-            pl.BlockSpec((1, t, 1), lambda ib, ih, ic: (ib, ic, ih)),
-            pl.BlockSpec((1,), lambda ib, ih, ic: (ih,)),
-            pl.BlockSpec((1, t, n), lambda ib, ih, ic: (ib, ic, 0)),
-            pl.BlockSpec((1, t, n), lambda ib, ih, ic: (ib, ic, 0)),
+            pl.BlockSpec((None, None, t, p), lambda ib, ih, ic: (ib, ih, ic, 0)),
+            pl.BlockSpec((None, None, 1, t), lambda ib, ih, ic: (ib, ih, 0, ic)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, t, n), lambda ib, ih, ic: (ib, ic, 0)),
+            pl.BlockSpec((None, t, n), lambda ib, ih, ic: (ib, ic, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, t, 1, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
-            pl.BlockSpec((1, 1, p, n), lambda ib, ih, ic: (ib, ih, 0, 0)),
+            pl.BlockSpec((None, None, t, p), lambda ib, ih, ic: (ib, ih, ic, 0)),
+            pl.BlockSpec((None, None, p, n), lambda ib, ih, ic: (ib, ih, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct(xt.shape, x.dtype),
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A.astype(jnp.float32), Bm, Cm)
+    )(xt, dtt, A.astype(jnp.float32), Bm, Cm)
+    y = y.transpose(0, 2, 1, 3)
     if pad:
         y = y[:, :s]
     return y, h_final

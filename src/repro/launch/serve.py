@@ -19,6 +19,7 @@ from ..configs import registry
 from ..models import lm, steps
 from ..serving.kserve import InferenceService, Predictor
 from ..telemetry.events import EventLog
+from .compile_cache import use_compile_cache
 
 
 def make_lm_predictor(cfg, *, gen_tokens: int = 8, prompt_len: int = 16,
@@ -63,6 +64,7 @@ def main(argv=None):
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--gen-tokens", type=int, default=8)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = registry.get_smoke_config(args.arch)
     pred = make_lm_predictor(cfg, gen_tokens=args.gen_tokens)

@@ -13,6 +13,7 @@ from ..configs import registry
 from ..core.trainjob import LMTrainJob
 from ..telemetry.events import EventLog
 from . import mesh as mesh_mod
+from .compile_cache import use_compile_cache
 
 
 def main(argv=None):
@@ -27,6 +28,7 @@ def main(argv=None):
     ap.add_argument("--store", default="experiments/artifacts")
     ap.add_argument("--mesh", choices=("local", "none"), default="none")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = (registry.get_smoke_config(args.arch) if args.smoke
            else registry.get_config(args.arch))

@@ -156,7 +156,9 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
     P-token prompt costs O(P/C) calls instead of P decode steps while
     producing decode-identical logits: rows past a query's position differ
     (written here, zero in decode) but are masked to the same exact NEG_INF
-    before the softmax.  Returns (out (B,C,D), new_cache)."""
+    before the softmax.  Windowed layers attend over the same keys in
+    another order, so there the softmax sums may differ in the last bits.
+    Returns (out (B,C,D), new_cache)."""
     b, c, _ = x.shape
     hq = cfg.n_heads
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
@@ -171,29 +173,31 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
     k_cd, v_cd = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
 
     if window > 0:
-        # Ring buffer: reconstruct, per query, the ring exactly as it stood
-        # at that query's decode step.  Slot s at time t holds position
-        # cand = t - ((t - s) % size); if cand falls inside this chunk the
-        # key is a chunk row, otherwise it is the pre-chunk ring content.
+        # Ring buffer of `size` slots.  At query position t the decode step
+        # sees exactly the positions in (t - size, t] that are >= 0: the
+        # pre-chunk ring rows the chunk has not yet overwritten, plus the
+        # chunk's own rows up to t.  Every query attends over the shared key
+        # set [ring ; chunk] under that mask, so the scores are C x (size+C)
+        # and no per-query copy of the ring is built (at size 2048, C 256
+        # and 32 heads that copy was 7.5 GB).
         size = min(window, cache_size)
         slots = jnp.arange(size)
         start = tpos[:, :1]                                        # chunk offset
-        cand = tpos[:, :, None] - ((tpos[:, :, None] - slots[None, None, :]) % size)
-        from_chunk = cand >= start[:, :, None]                     # (B,C,size)
-        idx = jnp.clip(cand - start[:, :, None], 0, c - 1)
-        b3 = jnp.arange(b)[:, None, None]
-        sel = from_chunk[..., None, None]
-        keys = jnp.where(sel, k_cd[b3, idx], cache["k"][:, None])  # (B,C,size,Hkv,hd)
-        vals = jnp.where(sel, v_cd[b3, idx], cache["v"][:, None])
-        keys = jnp.repeat(keys, group, axis=3) if group > 1 else keys
-        vals = jnp.repeat(vals, group, axis=3) if group > 1 else vals
-        logits = jnp.einsum("bqhd,bqkhd->bqhk", q.astype(keys.dtype), keys,
+        # position each slot held before the chunk (< 0: never written)
+        held = (start - 1) - ((start - 1 - slots[None, :]) % size)   # (B,size)
+        kpos = jnp.concatenate([held, tpos], axis=1)               # (B,size+C)
+        keys = jnp.concatenate([cache["k"], k_cd], axis=1)         # (B,size+C,Hkv,hd)
+        vals = jnp.concatenate([cache["v"], v_cd], axis=1)
+        keys = jnp.repeat(keys, group, axis=2) if group > 1 else keys
+        vals = jnp.repeat(vals, group, axis=2) if group > 1 else vals
+        logits = jnp.einsum("bqhd,bkhd->bqhk", q.astype(keys.dtype), keys,
                             preferred_element_type=jnp.float32) * scale
-        eff_len = jnp.minimum(tpos + 1, size)                      # (B,C)
-        valid = slots[None, None, :] < eff_len[:, :, None]
+        kq = kpos[:, None, :]
+        tq = tpos[:, :, None]
+        valid = (kq >= 0) & (kq <= tq) & (kq > tq - size)          # (B,C,size+C)
         logits = jnp.where(valid[:, :, None, :], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)
-        o = jnp.einsum("bqhk,bqkhd->bqhd", probs.astype(vals.dtype), vals,
+        o = jnp.einsum("bqhk,bkhd->bqhd", probs.astype(vals.dtype), vals,
                        preferred_element_type=jnp.float32).astype(q.dtype)
         # final ring state: per slot, the last chunk position that maps there
         # (deterministic gather -- scatter with duplicate ring indices is not)

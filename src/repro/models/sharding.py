@@ -81,16 +81,10 @@ def use_mesh(mesh: Optional[Mesh]):
 
 
 def abstract_mesh(shape: Sequence[int], names: Sequence[str]):
-    """Version-portable ``jax.sharding.AbstractMesh`` constructor.
-
-    jax<=0.4.x takes a single ``((name, size), ...)`` tuple; jax>=0.5 takes
-    ``(axis_sizes, axis_names)``.  Tests build abstract meshes for rule
-    resolution without devices, so they go through this shim."""
+    """A device-free ``jax.sharding.AbstractMesh``: tests resolve sharding
+    rules against it without devices."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(names, shape)))
-    except TypeError:
-        return AbstractMesh(tuple(shape), tuple(names))
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 def _resolve(spec: Sequence[Any], mesh: Mesh) -> P:

@@ -149,10 +149,18 @@ class ContinuousBatcher:
             s.req = None
 
     def _prefill_into(self, i: int, req: Request) -> int:
-        """Run the prompt through lm.prefill_chunk on a one-row cache, then
-        scatter the produced cache rows into slot i.  Returns the first
-        generated token (argmax of the last prompt position's logits)."""
-        prompt = np.asarray(req.prompt, np.int32)
+        """Prefill the prompt, then scatter the produced cache rows into
+        slot i.  Returns the first generated token (argmax of the last
+        prompt position's logits)."""
+        logits, cache = self.prefill(req.prompt)
+        self._scatter_row(i, cache)
+        return int(np.asarray(jnp.argmax(logits)))
+
+    def prefill(self, prompt: list):
+        """Run a prompt through lm.prefill_chunk on a zeroed one-row cache,
+        `prefill_chunk` tokens per call.  Returns (logits of the last prompt
+        position (V,), the one-row cache)."""
+        prompt = np.asarray(prompt, np.int32)
         n = len(prompt)
         cache = self._row_cache_zeros
         t0 = 0
@@ -168,8 +176,7 @@ class ContinuousBatcher:
             t0 += c
         self.prefill_stats["tokens"] += n
         self.prefill_stats["requests"] += 1
-        self._scatter_row(i, cache)
-        return int(np.asarray(jnp.argmax(logits[:, -1], axis=-1))[0])
+        return logits[0, -1], cache
 
     def _scatter_row(self, i: int, row_cache):
         """Copy a one-row prefill cache into row i of the shared cache."""

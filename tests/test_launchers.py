@@ -91,3 +91,50 @@ def test_serve_cli_end_to_end(cli):
     assert r.returncode == 0, r.stderr[-500:]
     out = json.loads(r.stdout)
     assert out["n"] == 6
+
+
+def _entries(d):
+    return sorted(p.name for p in d.iterdir()) if d.is_dir() else []
+
+
+def test_compile_cache_follows_env_dir_and_nowhere_else(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: entries land there, none in the
+    checkout's own cache directory."""
+    import os
+    from repro.launch.compile_cache import DEFAULT_DIR
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import use_compile_cache\n"
+            "print(use_compile_cache())\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()\n")
+    env_dir = tmp_path / "cc"
+    before = _entries(DEFAULT_DIR)
+    r = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=300, cwd=os.path.dirname(os.path.dirname(__file__)),
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu",
+             "JAX_COMPILATION_CACHE_DIR": str(env_dir),
+             # cache even this tiny program
+             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+             "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0"})
+    assert r.returncode == 0, r.stderr[-500:]
+    assert r.stdout.strip() == str(env_dir)
+    assert _entries(env_dir), "no cache entry written to JAX_COMPILATION_CACHE_DIR"
+    assert _entries(DEFAULT_DIR) == before
+
+
+def test_compile_cache_defaults_to_fixed_ignored_dir_in_checkout(monkeypatch):
+    import os
+    from pathlib import Path
+
+    import jax
+    from repro.launch.compile_cache import DEFAULT_DIR, use_compile_cache
+    root = Path(__file__).resolve().parents[1]
+    assert DEFAULT_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert use_compile_cache() == str(DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -65,11 +65,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, functools
 from repro.configs import registry
 from repro.launch import shardings
+from repro.launch.mesh import make_mesh
 from repro.models import sharding as msh, steps
 from repro.launch.roofline import collective_bytes, cost_dict, roofline
 
 cfg = registry.get_smoke_config("granite_3_8b").replace(dtype="bfloat16")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 param_spec = steps.params_spec(cfg)
 param_sh = msh.param_shardings(param_spec, mesh)
 opt_spec = steps.opt_state_spec(param_spec)
@@ -107,12 +108,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, functools
 from repro.configs import registry
 from repro.launch import shardings
+from repro.launch.mesh import make_mesh
 from repro.models import sharding as msh, steps
 from repro.launch.roofline import collective_bytes
 
 cfg = registry.get_smoke_config("xlstm_1_3b").replace(
     dtype="bfloat16", sharding_profile="dp", zero1=True)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with msh.use_profile("dp"), msh.use_mesh(mesh):
     param_spec = steps.params_spec(cfg)
     param_sh = msh.param_shardings(param_spec, mesh)

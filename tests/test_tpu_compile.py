@@ -1,0 +1,128 @@
+"""Compile for a described TPU v5e, at real widths, without a chip.
+
+The TPU compiler is installed beside JAX and compiles for a chip that is
+described and not attached.  What interpret mode cannot show -- block
+shapes Mosaic refuses, VMEM overflow, a program larger than HBM -- fails
+here.  Nothing runs, so nothing here says anything about results or times.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and each test worker imports
+every test file.  Keep these tests in this one file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import registry
+from repro.kernels.decode_attention import decode_attention
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.mlstm_scan import mlstm_scan
+from repro.kernels.rmsnorm import rmsnorm
+from repro.kernels.ssm_scan import ssm_scan
+from repro.models import lm
+
+HBM_BYTES = 15.75e9       # what XLA lets one v5e program use
+SEQ = 2048                # kernel sequence length
+SLOTS, MAX_LEN, CHUNK = 8, 2048, 256   # chip_smoke.py's batcher
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache off around these
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        try:
+            yield topologies.get_topology_desc(platform="tpu",
+                                               topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        finally:
+            jax.config.update("jax_enable_compilation_cache", prev)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_case(name, cfg):
+    """(fn, [(shape, dtype)]) for one kernel at cfg's widths."""
+    bf, f32 = jnp.bfloat16, jnp.float32
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    if name == "flash":
+        window = cfg.sliding_window if "local" in cfg.block_pattern else 0
+        return (lambda q, k, v: flash_attention(q, k, v, window=window,
+                                                interpret=False),
+                [((1, SEQ, hq, hd), bf), ((1, SEQ, hkv, hd), bf),
+                 ((1, SEQ, hkv, hd), bf)])
+    if name == "decode":
+        return (lambda q, k, v, n: decode_attention(q, k, v, n, interpret=False),
+                [((SLOTS, hq, hd), bf), ((SLOTS, MAX_LEN, hkv, hd), bf),
+                 ((SLOTS, MAX_LEN, hkv, hd), bf), ((SLOTS,), jnp.int32)])
+    if name == "ssm":
+        p, n = cfg.d_inner // hq, cfg.ssm_state
+        return (lambda x, dt, a, b, c: ssm_scan(x, dt, a, b, c,
+                                                chunk=cfg.ssm_chunk,
+                                                interpret=False),
+                [((1, SEQ, hq, p), bf), ((1, SEQ, hq), bf), ((hq,), f32),
+                 ((1, SEQ, n), bf), ((1, SEQ, n), bf)])
+    if name == "mlstm":
+        d = cfg.d_model // hq
+        return (lambda q, k, v, li, lf: mlstm_scan(q, k, v, li, lf,
+                                                   chunk=cfg.ssm_chunk,
+                                                   interpret=False),
+                [((1, SEQ, hq, d), bf)] * 3 + [((1, SEQ, hq), f32)] * 2)
+    if name == "rmsnorm":
+        return (lambda x, s: rmsnorm(x, s, interpret=False),
+                [((SEQ, cfg.d_model), bf), ((cfg.d_model,), bf)])
+    raise ValueError(name)
+
+
+@pytest.mark.parametrize("kernel,arch", [
+    ("flash", "h2o_danube_3_4b"), ("flash", "zamba2_1_2b"),
+    ("decode", "h2o_danube_3_4b"), ("decode", "zamba2_1_2b"),
+    ("ssm", "zamba2_1_2b"), ("mlstm", "xlstm_1_3b"),
+    ("rmsnorm", "h2o_danube_3_4b"), ("rmsnorm", "zamba2_1_2b"),
+    ("rmsnorm", "xlstm_1_3b"),
+])
+def test_kernel_compiles_for_v5e(one_chip, kernel, arch):
+    fn, shapes = _kernel_case(kernel, registry.get_config(arch))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        f"{kernel} at {arch} widths compiled without its Pallas kernel"
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
+def test_h2o_serving_step_fits_one_v5e(one_chip, step):
+    """The batcher's two programs for h2o-danube-3-4b at published widths,
+    bf16 weights, at chip_smoke.py's slots and length."""
+    cfg = registry.get_config("h2o_danube_3_4b").replace(param_dtype="bfloat16")
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    params = place(jax.eval_shape(lambda: lm.init_params(jax.random.PRNGKey(0),
+                                                         cfg)))
+    rows, width = (SLOTS, 1) if step == "decode_step" else (1, CHUNK)
+    cache = place(jax.eval_shape(lambda: lm.init_cache(cfg, rows, MAX_LEN)))
+    tok = jax.ShapeDtypeStruct((rows, width), jnp.int32, sharding=one_chip)
+    pos_shape = (SLOTS,) if step == "decode_step" else (1, CHUNK)
+    pos = jax.ShapeDtypeStruct(pos_shape, jnp.int32, sharding=one_chip)
+    fn = getattr(lm, step)
+    compiled = jax.jit(lambda p, c, t, q: fn(p, cfg, t, q, c)).lower(
+        params, cache, tok, pos).compile()
+    m = compiled.memory_analysis()
+    used = m.argument_size_in_bytes + m.output_size_in_bytes \
+        + m.temp_size_in_bytes
+    assert used < HBM_BYTES, f"{step}: {used / 1e9:.2f} GB"
