@@ -60,6 +60,9 @@ def main():
         "wall_s": round(wall, 2),
         "tokens_generated": sum(len(r.output) for r in reqs),
         "admission_steps": [r.admitted_step for r in reqs],
+        # from the requests' own clock times (time.perf_counter())
+        "queue_wait_ms": [round((r.t_admit - r.t_submit) * 1e3, 2) for r in reqs],
+        "ttft_ms": [round((r.t_first - r.t_submit) * 1e3, 2) for r in reqs],
         "sample_output": reqs[0].output,
     }
     if args.prefill_chunk:

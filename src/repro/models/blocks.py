@@ -171,18 +171,27 @@ def slot_forward(p: Params, x: jax.Array, positions, cfg: ArchConfig,
     return x, cache, aux
 
 
+def mixer_scope(kind: str) -> str:
+    """Name scope of a mixer's ops: "attention" for the attention kinds,
+    else the recurrent kind's own name.  A trace's op metadata carries it,
+    so device time can be read by layer part across refactors."""
+    return "attention" if kind in ("global", "local", "mla") else kind
+
+
 def slot_decode(p: Params, x: jax.Array, cache, positions, cfg: ArchConfig,
                 kind: str, ffn: str, *, enc_kv=None):
-    mix_out, new_cache = _mixer_decode(
-        p["mixer"], nn.rms_norm(x, p["norm1"], cfg.norm_eps), cache, positions, cfg, kind)
+    xn = nn.rms_norm(x, p["norm1"], cfg.norm_eps)
+    with jax.named_scope(mixer_scope(kind)):
+        mix_out, new_cache = _mixer_decode(p["mixer"], xn, cache, positions, cfg, kind)
     x = x + mix_out
     if enc_kv is not None:
         x = x + attn.cross_decode(p["cross"], nn.rms_norm(x, p["norm_x"], cfg.norm_eps),
                                   enc_kv, cfg)
     if ffn != "none":
         h = nn.rms_norm(x, p["norm2"], cfg.norm_eps)
-        y = (moe_mod.moe_forward(p["ffn"], h, cfg)[0] if ffn == "moe"
-             else mlp_forward(p["ffn"], h, cfg))
+        with jax.named_scope("ffn"):
+            y = (moe_mod.moe_forward(p["ffn"], h, cfg)[0] if ffn == "moe"
+                 else mlp_forward(p["ffn"], h, cfg))
         x = x + y
     return x, new_cache
 
@@ -205,14 +214,15 @@ def slot_prefill(p: Params, x: jax.Array, cache, positions, cfg: ArchConfig,
     Attention kinds batch all C queries against the cache with decode-exact
     masking; recurrent kinds scan the exact decode recurrence.  FFN / norms
     are position-independent row ops and run batched."""
-    mix_out, new_cache = _mixer_prefill(
-        p["mixer"], nn.rms_norm(x, p["norm1"], cfg.norm_eps), cache,
-        positions, cfg, kind)
+    xn = nn.rms_norm(x, p["norm1"], cfg.norm_eps)
+    with jax.named_scope(mixer_scope(kind)):
+        mix_out, new_cache = _mixer_prefill(p["mixer"], xn, cache, positions, cfg, kind)
     x = x + mix_out
     if ffn != "none":
         h = nn.rms_norm(x, p["norm2"], cfg.norm_eps)
-        y = (moe_mod.moe_forward(p["ffn"], h, cfg, no_drop=True)[0]
-             if ffn == "moe" else mlp_forward(p["ffn"], h, cfg))
+        with jax.named_scope("ffn"):
+            y = (moe_mod.moe_forward(p["ffn"], h, cfg, no_drop=True)[0]
+                 if ffn == "moe" else mlp_forward(p["ffn"], h, cfg))
         x = x + y
     return x, new_cache
 

@@ -24,11 +24,13 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import ArchConfig
 from ..models import lm
@@ -45,6 +47,17 @@ class Request:
     done: bool = False
     admitted_step: int = -1
     finished_step: int = -1
+    # time.perf_counter() at submit, admission, first token and completion
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_done: Optional[float] = None
+
+
+def _emit(req: Request, token: int):
+    req.output.append(token)
+    if len(req.output) == 1:
+        req.t_first = time.perf_counter()
 
 
 @dataclasses.dataclass
@@ -73,11 +86,16 @@ class ContinuousBatcher:
         self.requests: list[Request] = []   # submitted, not yet run()-returned
         self.step_count = 0
         self._next_rid = 0
-        self._decode = jax.jit(
-            lambda p, c, t, pos: lm.decode_step(p, cfg, t, pos, c))
+
+        def serve_decode(p, c, t, pos):
+            return lm.decode_step(p, cfg, t, pos, c)
+
+        def serve_prefill(p, c, t, pos):
+            return lm.prefill_chunk(p, cfg, t, pos, c)
+
+        self._decode = jax.jit(serve_decode)
         if self.prefill_chunk > 0:
-            self._prefill = jax.jit(
-                lambda p, c, t, pos: lm.prefill_chunk(p, cfg, t, pos, c))
+            self._prefill = jax.jit(serve_prefill)
             self._row_cache_zeros = lm.init_cache(cfg, 1, max_len)
             # per-phase counters the disaggregated cost model reads
             self.prefill_stats = {"requests": 0, "chunks": 0, "tokens": 0}
@@ -94,7 +112,8 @@ class ContinuousBatcher:
                 "no room in the KV cache to generate")
         # rid must be monotonic, not len(queue): admission pops the queue, so
         # a later submit would reuse a live rid and corrupt run()'s seen-set.
-        req = Request(rid=self._next_rid, prompt=list(prompt), max_new=max_new)
+        req = Request(rid=self._next_rid, prompt=list(prompt), max_new=max_new,
+                      t_submit=time.perf_counter())
         self._next_rid += 1
         self.queue.append(req)
         self.requests.append(req)
@@ -112,11 +131,12 @@ class ContinuousBatcher:
             if a.ndim >= 2 and a.shape[1] == self.max_slots:
                 return a.at[:, i].set(jnp.zeros_like(a[:, i]))
             return a
-        self.cache = {
-            k: (jax.tree_util.tree_map(zero_row, v) if k.startswith("phase")
-                else v)
-            for k, v in self.cache.items()
-        }
+        with TraceAnnotation("serve.reset_row"):
+            self.cache = {
+                k: (jax.tree_util.tree_map(zero_row, v) if k.startswith("phase")
+                    else v)
+                for k, v in self.cache.items()
+            }
 
     def _admit(self):
         for i, s in enumerate(self.slots):
@@ -124,21 +144,23 @@ class ContinuousBatcher:
             # eos / cache bound), freeing the slot for the next in queue
             while s.req is None and self.queue:
                 req = self.queue.popleft()
-                req.admitted_step = self.step_count
-                s.req = req
-                self._reset_row(i)
-                if self.prefill_chunk > 0 and req.prompt:
-                    first = self._prefill_into(i, req)
-                    req.output.append(first)
-                    s.pos = len(req.prompt)
-                    s.remaining_prompt = 0
-                    self._maybe_finish(s)
-                else:
-                    s.pos = 0
-                    s.remaining_prompt = len(req.prompt)
+                with TraceAnnotation("serve.admit", rid=req.rid):
+                    req.admitted_step = self.step_count
+                    req.t_admit = time.perf_counter()
+                    s.req = req
+                    self._reset_row(i)
+                    if self.prefill_chunk > 0 and req.prompt:
+                        _emit(req, self._prefill_into(i, req))
+                        s.pos = len(req.prompt)
+                        s.remaining_prompt = 0
+                        self._maybe_finish(s)
+                    else:
+                        s.pos = 0
+                        s.remaining_prompt = len(req.prompt)
 
     def _maybe_finish(self, s: _Slot):
-        """Same termination predicate the decode loop applies each step."""
+        """Free the slot if its request is complete: max_new tokens, eos,
+        or the end of the cache row."""
         req = s.req
         hit_eos = self.eos_id is not None and req.output \
             and req.output[-1] == self.eos_id
@@ -146,34 +168,38 @@ class ContinuousBatcher:
                            or s.pos >= self.max_len - 1):
             req.done = True
             req.finished_step = self.step_count
-            s.req = None
+            req.t_done = time.perf_counter()
+            s.req = None               # free the slot for admission
 
     def _prefill_into(self, i: int, req: Request) -> int:
         """Prefill the prompt, then scatter the produced cache rows into
         slot i.  Returns the first generated token (argmax of the last
         prompt position's logits)."""
-        logits, cache = self.prefill(req.prompt)
+        logits, cache = self.prefill(req.prompt, rid=req.rid)
         self._scatter_row(i, cache)
-        return int(np.asarray(jnp.argmax(logits)))
+        with TraceAnnotation("serve.admit.sync"):
+            return int(np.asarray(jnp.argmax(logits)))
 
-    def prefill(self, prompt: list):
+    def prefill(self, prompt: list, rid: int = -1):
         """Run a prompt through lm.prefill_chunk on a zeroed one-row cache,
         `prefill_chunk` tokens per call.  Returns (logits of the last prompt
-        position (V,), the one-row cache)."""
+        position (V,), the one-row cache).  `rid` only labels the trace
+        span (-1: no request)."""
         prompt = np.asarray(prompt, np.int32)
         n = len(prompt)
         cache = self._row_cache_zeros
         t0 = 0
         logits = None
-        while t0 < n:
-            c = min(self.prefill_chunk, n - t0)
-            tok = jnp.asarray(prompt[t0:t0 + c], jnp.int32)[None]
-            pos = jnp.arange(t0, t0 + c, dtype=jnp.int32)[None]
-            if self.cfg.use_mrope:
-                pos = jnp.broadcast_to(pos[:, None], (1, 3, c))
-            logits, cache = self._prefill(self.params, cache, tok, pos)
-            self.prefill_stats["chunks"] += 1
-            t0 += c
+        with TraceAnnotation("serve.prefill", rid=rid, tokens=n):
+            while t0 < n:
+                c = min(self.prefill_chunk, n - t0)
+                tok = jnp.asarray(prompt[t0:t0 + c], jnp.int32)[None]
+                pos = jnp.arange(t0, t0 + c, dtype=jnp.int32)[None]
+                if self.cfg.use_mrope:
+                    pos = jnp.broadcast_to(pos[:, None], (1, 3, c))
+                logits, cache = self._prefill(self.params, cache, tok, pos)
+                self.prefill_stats["chunks"] += 1
+                t0 += c
         self.prefill_stats["tokens"] += n
         self.prefill_stats["requests"] += 1
         return logits[0, -1], cache
@@ -184,16 +210,30 @@ class ContinuousBatcher:
             if dst.ndim >= 2 and dst.shape[1] == self.max_slots:
                 return dst.at[:, i].set(src[:, 0].astype(dst.dtype))
             return dst
-        self.cache = {
-            k: (jax.tree_util.tree_map(put, v, row_cache[k])
-                if k.startswith("phase") else v)
-            for k, v in self.cache.items()
-        }
+        with TraceAnnotation("serve.scatter_row"):
+            self.cache = {
+                k: (jax.tree_util.tree_map(put, v, row_cache[k])
+                    if k.startswith("phase") else v)
+                for k, v in self.cache.items()
+            }
 
     # -- engine -------------------------------------------------------------
     def step(self):
         """Advance every slot one token; admit queued work into free slots."""
-        self._admit()
+        with TraceAnnotation("serve.step"):
+            self._admit()
+            with TraceAnnotation("serve.step.inputs"):
+                tok, pos = self._inputs()
+            with TraceAnnotation("serve.step.dispatch"):
+                logits, self.cache = self._decode(self.params, self.cache, tok, pos)
+            with TraceAnnotation("serve.step.sync"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with TraceAnnotation("serve.step.feedback"):
+                self._feedback(nxt)
+            self.step_count += 1
+
+    def _inputs(self):
+        """Each slot's next token and position, on the device."""
         tokens, positions = [], []
         for s in self.slots:
             if s.req is None:
@@ -211,9 +251,10 @@ class ContinuousBatcher:
         pos = jnp.asarray(positions, jnp.int32)
         if self.cfg.use_mrope:
             pos = jnp.broadcast_to(pos[:, None], (self.max_slots, 3))
-        logits, self.cache = self._decode(self.params, self.cache, tok, pos)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        return tok, pos
 
+    def _feedback(self, nxt):
+        """Advance each busy slot by the token the step made for it."""
         for i, s in enumerate(self.slots):
             if s.req is None:
                 continue
@@ -221,17 +262,10 @@ class ContinuousBatcher:
             if s.remaining_prompt > 0:
                 s.remaining_prompt -= 1
                 if s.remaining_prompt == 0:
-                    s.req.output.append(int(nxt[i]))   # first generated token
+                    _emit(s.req, int(nxt[i]))   # first generated token
             else:
-                s.req.output.append(int(nxt[i]))
-            hit_eos = self.eos_id is not None and s.req.output \
-                and s.req.output[-1] == self.eos_id
-            if s.req.output and (len(s.req.output) >= s.req.max_new or hit_eos
-                                 or s.pos >= self.max_len - 1):
-                s.req.done = True
-                s.req.finished_step = self.step_count
-                s.req = None               # free the slot for admission
-        self.step_count += 1
+                _emit(s.req, int(nxt[i]))
+            self._maybe_finish(s)
 
     def run(self, max_steps: int = 10_000) -> list:
         """Drain the queue; returns requests finished since the last run()

@@ -148,3 +148,19 @@ def test_admission_is_fifo_under_backlog(setup):
     assert admits == sorted(admits)              # FIFO: never leapfrogged
     assert all(r.admitted_step >= 0 and r.finished_step >= r.admitted_step
                for r in reqs)
+
+
+@pytest.mark.parametrize("prefill_chunk", [0, 4])
+def test_requests_carry_their_clock_times(setup, prefill_chunk):
+    """t_submit <= t_admit <= t_first <= t_done on both admission paths;
+    under backlog the last request waits in the queue longest."""
+    cfg, params = setup
+    cb = ContinuousBatcher(cfg, params, max_slots=2, max_len=64,
+                           prefill_chunk=prefill_chunk)
+    reqs = [cb.submit([i + 1, i + 2, i + 3], max_new=3) for i in range(5)]
+    assert all(r.t_admit is None and r.t_first is None for r in reqs)
+    cb.run()
+    for r in reqs:
+        assert r.t_submit <= r.t_admit <= r.t_first <= r.t_done
+    waits = [r.t_admit - r.t_submit for r in reqs]
+    assert waits[-1] == max(waits)
