@@ -20,7 +20,8 @@ def rmsnorm_ref(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 def _gqa_expand(k: jax.Array, n_q_heads: int) -> jax.Array:
-    """(B,S,Hkv,D) -> (B,S,Hq,D) by repeating kv heads."""
+    """(B,S,Hkv,D) -> (B,S,Hq,D) by repeating kv heads.  Only the
+    full-sequence oracle uses it; decode contracts grouped queries instead."""
     b, s, hkv, d = k.shape
     group = n_q_heads // hkv
     return jnp.repeat(k, group, axis=2) if group > 1 else k
@@ -70,22 +71,26 @@ def decode_attention_ref(
     *,
     scale: float | None = None,
 ) -> jax.Array:
-    """Single-token decode attention against a (padded) KV cache."""
+    """Single-token decode attention against a (padded) KV cache.
+
+    Query head h reads KV head h // G (G = Hq // Hkv), as a repeat of the
+    cache would give it, but the G query heads of a group are contracted
+    together against the unexpanded cache: K and V are read once each, in
+    their own dtype, and no per-query-head copy of the cache is built (a
+    one-query matvec over a repeated cache lowers to an f32 broadcast of
+    the whole cache).  With G = 1 the reshape is the identity."""
     b, hq, d = q.shape
-    s = k_cache.shape[1]
+    s, hkv = k_cache.shape[1], k_cache.shape[2]
     scale = scale if scale is not None else d ** -0.5
-    k = _gqa_expand(k_cache, hq)
-    v = _gqa_expand(v_cache, hq)
-    # native-dtype dots with f32 accumulation: never materialise an f32
-    # copy of the KV cache (the dominant decode byte term, §Perf C1)
-    logits = jnp.einsum("bhd,bkhd->bhk", q.astype(k.dtype), k,
+    qg = q.astype(k_cache.dtype).reshape(b, hkv, hq // hkv, d)   # (B,Hkv,G,D)
+    logits = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache,
                         preferred_element_type=jnp.float32) * scale
     valid = jnp.arange(s)[None, :] < cache_len[:, None]          # (B, S)
-    logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhk,bkhd->bhd", probs.astype(v.dtype), v,
+    out = jnp.einsum("bhgk,bkhd->bhgd", probs.astype(v_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    return out.reshape(b, hq, d).astype(q.dtype)
 
 
 def ssm_scan_ref(

@@ -151,8 +151,8 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
 
     x: (B,C,D); positions: (B,C) (or (B,3,C) M-RoPE) absolute, contiguous
     ascending; cache {k,v:(B,S,Hkv,hd)}.  Writes the chunk's K/V rows into
-    the cache and attends every query with the same masked softmax the
-    one-token decode path (`gqa_decode` -> decode_attention_ref) uses, so a
+    the cache and attends every query with the same grouped masked softmax
+    the one-token decode path (`gqa_decode` -> decode_attention_ref) uses, so a
     P-token prompt costs O(P/C) calls instead of P decode steps while
     producing decode-identical logits: rows past a query's position differ
     (written here, zero in decode) but are masked to the same exact NEG_INF
@@ -160,7 +160,6 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
     another order, so there the softmax sums may differ in the last bits.
     Returns (out (B,C,D), new_cache)."""
     b, c, _ = x.shape
-    hq = cfg.n_heads
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -168,8 +167,6 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
     k = _rope(k, positions, cfg)                                   # (B,C,Hkv,hd)
     tpos = _tpos(positions, cfg)                                   # (B,C) int
     cache_size = cache["k"].shape[1]
-    scale = q.shape[-1] ** -0.5
-    group = max(hq // k.shape[2], 1)
     k_cd, v_cd = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
 
     if window > 0:
@@ -188,17 +185,10 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
         kpos = jnp.concatenate([held, tpos], axis=1)               # (B,size+C)
         keys = jnp.concatenate([cache["k"], k_cd], axis=1)         # (B,size+C,Hkv,hd)
         vals = jnp.concatenate([cache["v"], v_cd], axis=1)
-        keys = jnp.repeat(keys, group, axis=2) if group > 1 else keys
-        vals = jnp.repeat(vals, group, axis=2) if group > 1 else vals
-        logits = jnp.einsum("bqhd,bkhd->bqhk", q.astype(keys.dtype), keys,
-                            preferred_element_type=jnp.float32) * scale
         kq = kpos[:, None, :]
         tq = tpos[:, :, None]
         valid = (kq >= 0) & (kq <= tq) & (kq > tq - size)          # (B,C,size+C)
-        logits = jnp.where(valid[:, :, None, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        o = jnp.einsum("bqhk,bkhd->bqhd", probs.astype(vals.dtype), vals,
-                       preferred_element_type=jnp.float32).astype(q.dtype)
+        o = _grouped_attend(q, keys, vals, valid)
         # final ring state: per slot, the last chunk position that maps there
         # (deterministic gather -- scatter with duplicate ring indices is not)
         last = tpos[:, -1:]
@@ -212,17 +202,27 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
         b2 = jnp.arange(b)[:, None]
         k_cache = cache["k"].at[b2, tpos].set(k_cd)
         v_cache = cache["v"].at[b2, tpos].set(v_cd)
-        keys = jnp.repeat(k_cache, group, axis=2) if group > 1 else k_cache
-        vals = jnp.repeat(v_cache, group, axis=2) if group > 1 else v_cache
-        logits = jnp.einsum("bqhd,bkhd->bqhk", q.astype(keys.dtype), keys,
-                            preferred_element_type=jnp.float32) * scale
         valid = jnp.arange(cache_size)[None, None, :] < (tpos[:, :, None] + 1)
-        logits = jnp.where(valid[:, :, None, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        o = jnp.einsum("bqhk,bkhd->bqhd", probs.astype(vals.dtype), vals,
-                       preferred_element_type=jnp.float32).astype(q.dtype)
+        o = _grouped_attend(q, k_cache, v_cache, valid)
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     return out, {"k": k_cache, "v": v_cache}
+
+
+def _grouped_attend(q: jax.Array, keys: jax.Array, vals: jax.Array,
+                    valid: jax.Array) -> jax.Array:
+    """q (B,C,Hq,hd) against keys/vals (B,K,Hkv,hd) under valid (B,C,K):
+    decode_attention_ref's grouped contraction for C queries at once (query
+    head h reads KV head h // G), with no per-query-head copy of the keys."""
+    b, c, hq, hd = q.shape
+    hkv = keys.shape[2]
+    qg = q.astype(keys.dtype).reshape(b, c, hkv, hq // hkv, hd)
+    logits = jnp.einsum("bqhgd,bkhd->bqhgk", qg, keys,
+                        preferred_element_type=jnp.float32) * hd ** -0.5
+    logits = jnp.where(valid[:, :, None, None, :], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    o = jnp.einsum("bqhgk,bkhd->bqhgd", probs.astype(vals.dtype), vals,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, c, hq, hd).astype(q.dtype)
 
 
 def gqa_decode_stacked(p: Params, x: jax.Array, stacked: dict, g: int,

@@ -67,6 +67,89 @@ def test_decode_attention_matches_ref(s, hq, hkv, d, block_k, dtype):
                                np.asarray(want, np.float32), **tol(dtype))
 
 
+GQA_HEADS = [(32, 8), (8, 8), (8, 1), (28, 4)]     # (Hq, Hkv): GQA, MHA, MQA, 7:1
+
+
+def _repeat_attend(q, k, v, valid):
+    """Float32 oracle in the repeat form: every KV head copied to its G
+    query heads, then plain masked attention.  q (B,Q,Hq,D), k/v
+    (B,K,Hkv,D), valid (B,Q,K) -> (B,Q,Hq,D)."""
+    q, k, v = (np.asarray(t, np.float32) for t in (q, k, v))
+    g = q.shape[2] // k.shape[2]
+    k, v = np.repeat(k, g, axis=2), np.repeat(v, g, axis=2)
+    logits = np.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    logits = np.where(np.asarray(valid)[:, None], logits, -np.inf)
+    w = np.exp(logits - logits.max(-1, keepdims=True))
+    w /= w.sum(-1, keepdims=True)
+    return np.einsum("bhqk,bkhd->bqhd", w, v)
+
+
+@pytest.mark.parametrize("hq,hkv", GQA_HEADS)
+@pytest.mark.parametrize("fill", ["one", "mid", "full"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_ref_matches_repeat_oracle(hq, hkv, fill, dtype):
+    """The grouped contraction equals attending over a repeated cache, and
+    rows at or past cache_len leave the output bit-for-bit unchanged."""
+    b, s, d = 2, 64, 16
+    ks = jax.random.split(jax.random.PRNGKey(hq * 10 + hkv), 3)
+    q = jax.random.normal(ks[0], (b, hq, d), dtype)
+    kc = jax.random.normal(ks[1], (b, s, hkv, d), dtype)
+    vc = jax.random.normal(ks[2], (b, s, hkv, d), dtype)
+    n = {"one": 1, "mid": s // 2 + 3, "full": s}[fill]
+    lens = jnp.array([n, max(1, n - 7)], jnp.int32)
+    got = ref.decode_attention_ref(q, kc, vc, lens)
+    valid = np.arange(s)[None, None, :] < np.asarray(lens)[:, None, None]
+    want = _repeat_attend(q[:, None], kc, vc, valid)[:, 0]
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, **tol(dtype))
+    dead = np.arange(s)[None, :, None, None] >= np.asarray(lens)[:, None, None, None]
+    pert = ref.decode_attention_ref(q, jnp.where(dead, 555.0, kc).astype(dtype),
+                                    jnp.where(dead, -555.0, vc).astype(dtype), lens)
+    np.testing.assert_array_equal(np.asarray(pert, np.float32),
+                                  np.asarray(got, np.float32))
+
+
+@pytest.mark.parametrize("hq,hkv", GQA_HEADS)
+@pytest.mark.parametrize("window", [0, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gqa_prefill_matches_repeat_oracle(hq, hkv, window, dtype):
+    """Both branches of gqa_prefill (global cache, ring of `window` slots)
+    against the repeat form over the whole written history: the query at
+    position t sees every earlier position, or those in (t - window, t]."""
+    from repro.configs import registry
+    from repro.models import attention
+    d_model, hd, c, s = 64, 16, 5, 32
+    start = 13 if window else 5                  # the ring has wrapped
+    cfg = registry.get_smoke_config("h2o_danube_3_4b").replace(
+        d_model=d_model, n_heads=hq, n_kv_heads=hkv, head_dim=hd)
+    ks = jax.random.split(jax.random.PRNGKey(hq * 10 + hkv + window), 4)
+    p = attention.gqa_init(ks[0], cfg, jnp.float32)
+    x = jax.random.normal(ks[1], (1, c, d_model))
+    hist_k = jax.random.normal(ks[2], (1, start, hkv, hd), dtype)
+    hist_v = jax.random.normal(ks[3], (1, start, hkv, hd), dtype)
+    size = window or s
+    held = np.full(size, -1)
+    for t in range(start):                       # what decode left in each slot
+        held[t % size] = t
+    rows = np.clip(held, 0, None)
+    cache = {"k": jnp.where((held >= 0)[None, :, None, None], hist_k[:, rows], 0),
+             "v": jnp.where((held >= 0)[None, :, None, None], hist_v[:, rows], 0)}
+    pos = jnp.arange(start, start + c, dtype=jnp.int32)[None]
+    got, _ = attention.gqa_prefill(p, x, cache, pos, cfg, window=window)
+
+    q = attention.apply_rope(jnp.einsum("bsd,dhk->bshk", x, p["wq"]), pos,
+                             cfg.rope_theta).astype(dtype)
+    k = attention.apply_rope(jnp.einsum("bsd,dhk->bshk", x, p["wk"]), pos,
+                             cfg.rope_theta).astype(dtype)
+    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"]).astype(dtype)
+    keys = jnp.concatenate([hist_k, k], axis=1)  # position i in row i
+    vals = jnp.concatenate([hist_v, v], axis=1)
+    tq, kp = np.asarray(pos)[:, :, None], np.arange(start + c)[None, None, :]
+    valid = (kp <= tq) & ((kp > tq - window) if window else True)
+    o = _repeat_attend(q, keys, vals, valid)
+    want = np.einsum("bshk,hkd->bsd", o, np.asarray(p["wo"], np.float32))
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, **tol(dtype))
+
+
 @pytest.mark.parametrize("s,h,p,n,chunk", [
     (64, 2, 8, 16, 16), (96, 3, 16, 8, 32), (50, 1, 4, 4, 16),
 ])

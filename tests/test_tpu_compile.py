@@ -9,6 +9,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and each test worker imports
 every test file.  Keep these tests in this one file.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -101,12 +104,9 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, arch):
         f"{kernel} at {arch} widths compiled without its Pallas kernel"
 
 
-@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
-def test_h2o_serving_step_fits_one_v5e(one_chip, step):
-    """The batcher's two programs for h2o-danube-3-4b at published widths,
-    bf16 weights, at chip_smoke.py's slots and length."""
-    cfg = registry.get_config("h2o_danube_3_4b").replace(param_dtype="bfloat16")
-
+def _compile_h2o_step(one_chip, step, cfg):
+    """lm.decode_step (8 slots, one token each) or lm.prefill_chunk (one
+    row, CHUNK tokens) for cfg, compiled for one described v5e."""
     def place(tree):
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
@@ -120,9 +120,38 @@ def test_h2o_serving_step_fits_one_v5e(one_chip, step):
     pos_shape = (SLOTS,) if step == "decode_step" else (1, CHUNK)
     pos = jax.ShapeDtypeStruct(pos_shape, jnp.int32, sharding=one_chip)
     fn = getattr(lm, step)
-    compiled = jax.jit(lambda p, c, t, q: fn(p, cfg, t, q, c)).lower(
+    return jax.jit(lambda p, c, t, q: fn(p, cfg, t, q, c)).lower(
         params, cache, tok, pos).compile()
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
+def test_h2o_serving_step_fits_one_v5e(one_chip, step):
+    """The batcher's two programs for h2o-danube-3-4b at published widths,
+    bf16 weights, at chip_smoke.py's slots and length."""
+    cfg = registry.get_config("h2o_danube_3_4b").replace(param_dtype="bfloat16")
+    compiled = _compile_h2o_step(one_chip, step, cfg)
     m = compiled.memory_analysis()
     used = m.argument_size_in_bytes + m.output_size_in_bytes \
         + m.temp_size_in_bytes
     assert used < HBM_BYTES, f"{step}: {used / 1e9:.2f} GB"
+
+
+def test_h2o_decode_step_contracts_grouped_queries_on_v5e(one_chip):
+    """The decode step as the benchmark serves it (global attention in every
+    layer, bf16) attends straight from the bf16 cache: no f32 temporary of
+    the cache's size spread over all 32 query heads, as a repeat of the 8
+    KV heads lowered to (0.29 GB of temporaries), and almost no temps."""
+    cfg = registry.get_config("h2o_danube_3_4b").replace(
+        param_dtype="bfloat16", block_pattern=("global",), sliding_window=0)
+    compiled = _compile_h2o_step(one_chip, "decode_step", cfg)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 64e6, f"decode step temporaries {temp / 1e9:.3f} GB"
+    per_head_cache = SLOTS * MAX_LEN * cfg.n_heads * cfg.head_dim
+    wide = []
+    for line in compiled.as_text().splitlines():
+        result = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\([^=]*?\)|\S+) ", line)
+        for dims in re.findall(r"f32\[([\d,]*)\]", result.group(1) if result else ""):
+            if math.prod(int(n) for n in dims.split(",") if n) >= per_head_cache:
+                wide.append(line.strip()[:160])
+    assert not wide, "f32 results of the cache's size per query head:\n" + \
+        "\n".join(wide)
