@@ -30,7 +30,7 @@ class ArchConfig:
     # sequence of block kinds tiled over depth; e.g. gemma3 ("local",)*5+("global",)
     block_pattern: Tuple[str, ...] = ("global",)
     sliding_window: int = 4096       # window for "local"/SWA blocks
-    mlp_act: str = "swiglu"          # swiglu | gelu | squared_relu
+    mlp_act: str = "swiglu"          # swiglu | geglu | gelu | squared_relu
     tie_embeddings: bool = False
     rope_theta: float = 10000.0
     use_rope: bool = True            # whisper: additive sinusoid instead
@@ -62,8 +62,19 @@ class ArchConfig:
     ssm_conv: int = 4
     d_inner_mult: int = 2            # d_inner = mult * d_model
     ssm_chunk: int = 256             # chunkwise-scan chunk length
+    ssm_heads: int = 0               # mamba2 heads; head dim d_inner // ssm_heads
+    ssm_groups: int = 1              # mamba2 B/C groups; head h reads group
+                                     # h // (ssm_heads // ssm_groups)
     slstm_every: int = 0             # xlstm: every Nth layer is sLSTM
-    shared_attn_every: int = 0       # zamba2: shared attention after every N ssm blocks
+
+    # hybrid (zamba2) ------------------------------------------------------------
+    # Invocation k of the shared attention+MLP blocks (block k % n_shared_blocks)
+    # runs before the mamba2 layer hybrid_layer_ids[k] and feeds its input.
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    n_shared_blocks: int = 0
+    adapter_rank: int = 0            # per-invocation LoRA on the shared MLP's
+                                     # gate/up projection (0: none)
+    attn_scale: float = 0.0          # softmax scale (0 -> head_dim ** -0.5)
 
     # enc-dec (whisper) --------------------------------------------------------
     encoder_layers: int = 0
@@ -107,6 +118,16 @@ class ArchConfig:
         return self.d_inner_mult * self.d_model
 
     @property
+    def ssm_head_dim(self) -> int:
+        return self.d_inner // self.ssm_heads
+
+    @property
+    def shared_in(self) -> int:
+        """Width of a hybrid shared block's input: the residual stream
+        concatenated with the original token embedding."""
+        return 2 * self.d_model
+
+    @property
     def supports_long_context(self) -> bool:
         """Sub-quadratic / bounded-cache decode available?  True for state
         recurrences (ssm/hybrid) and for archs with sliding-window layers
@@ -146,9 +167,12 @@ class ArchConfig:
             return (L - dense_l) * (attn + moe) + dense_l * (attn + 3 * D * F)
         if self.family == "hybrid":
             inner = self.d_inner
-            ssm_per = 2 * D * inner + inner * self.ssm_state
-            n_attn = L // max(self.shared_attn_every, 1)
-            return L * ssm_per + n_attn * (attn + 3 * D * F)
-        mlp = (3 if self.mlp_act == "swiglu" else 2) * D * F
+            ssm_per = D * (2 * inner + 2 * self.ssm_groups * self.ssm_state
+                           + self.ssm_heads) + inner * D
+            shared = (self.shared_in * hd * (Hq + 2 * Hkv) + Hq * hd * D + 3 * D * F
+                      + self.adapter_rank * (D + 2 * F) + D * D)
+            n_inv = sum(i < L for i in self.hybrid_layer_ids)
+            return L * ssm_per + n_inv * shared
+        mlp = (3 if self.mlp_act in ("swiglu", "geglu") else 2) * D * F
         enc = self.encoder_layers * (attn + mlp)
         return L * (attn + mlp) + enc

@@ -18,6 +18,7 @@ ARCH_IDS = (
     "minitron_4b",
     "qwen2_vl_7b",
     "zamba2_1_2b",
+    "zamba2_7b",
 )
 
 # public --arch ids (dashes) -> module names

@@ -202,7 +202,7 @@ def chunk_scan_correction_flops(cfg, shape_kind: str, batch: int, seq: int) -> f
         return 0.0
     mult = 3.0 if shape_kind == "train" else 1.0
     if cfg.family == "hybrid":                      # zamba2: mamba2 layers
-        H, P, N = cfg.n_heads, cfg.d_inner // cfg.n_heads, cfg.ssm_state
+        H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
         per_layer = 2 * B * S * T * (N + H * P) + 4 * B * S * H * P * N
         n_layers = cfg.n_layers
     else:                                           # xlstm: mLSTM layers

@@ -81,15 +81,21 @@ def _tpos(positions, cfg: ArchConfig):
 # ---------------------------------------------------------------------------
 # GQA attention
 # ---------------------------------------------------------------------------
-def gqa_init(key, cfg: ArchConfig, dtype) -> Params:
+def gqa_init(key, cfg: ArchConfig, dtype, d_in: int = 0) -> Params:
+    """Projections from d_in (default d_model) wide inputs back to d_model."""
     d, hq, hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    d_in = d_in or d
     k1, k2, k3, k4 = nn.split_keys(key, 4)
     return {
-        "wq": nn.dense_init(k1, (d, hq, hd), fan_in=d, dtype=dtype),
-        "wk": nn.dense_init(k2, (d, hkv, hd), fan_in=d, dtype=dtype),
-        "wv": nn.dense_init(k3, (d, hkv, hd), fan_in=d, dtype=dtype),
+        "wq": nn.dense_init(k1, (d_in, hq, hd), fan_in=d_in, dtype=dtype),
+        "wk": nn.dense_init(k2, (d_in, hkv, hd), fan_in=d_in, dtype=dtype),
+        "wv": nn.dense_init(k3, (d_in, hkv, hd), fan_in=d_in, dtype=dtype),
         "wo": nn.dense_init(k4, (hq, hd, d), fan_in=hq * hd, dtype=dtype),
     }
+
+
+def softmax_scale(cfg: ArchConfig) -> float:
+    return cfg.attn_scale or cfg.head_dim ** -0.5
 
 
 def gqa_forward(p: Params, x: jax.Array, positions: jax.Array, cfg: ArchConfig,
@@ -102,6 +108,7 @@ def gqa_forward(p: Params, x: jax.Array, positions: jax.Array, cfg: ArchConfig,
     q = constrain(_rope(q, positions, cfg), "batch", None, "model")
     k = constrain(_rope(k, positions, cfg), "batch", None, "model")
     o = ops.flash_attention(q, k, v, causal=causal, window=window,
+                            scale=softmax_scale(cfg),
                             use_kernel=cfg.use_kernels,
                             chunked=cfg.fused_attention,
                             chunk_k=cfg.attn_chunk,
@@ -140,7 +147,7 @@ def gqa_decode(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
     k_cache = cache["k"].at[bidx, slot].set(k.astype(cache["k"].dtype))
     v_cache = cache["v"].at[bidx, slot].set(v.astype(cache["v"].dtype))
     o = ops.decode_attention(q, k_cache, v_cache, eff_len.astype(jnp.int32),
-                             use_kernel=cfg.use_kernels)
+                             scale=softmax_scale(cfg), use_kernel=cfg.use_kernels)
     out = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None, :]
     return out, {"k": k_cache, "v": v_cache}
 
@@ -188,7 +195,7 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
         kq = kpos[:, None, :]
         tq = tpos[:, :, None]
         valid = (kq >= 0) & (kq <= tq) & (kq > tq - size)          # (B,C,size+C)
-        o = _grouped_attend(q, keys, vals, valid)
+        o = _grouped_attend(q, keys, vals, valid, softmax_scale(cfg))
         # final ring state: per slot, the last chunk position that maps there
         # (deterministic gather -- scatter with duplicate ring indices is not)
         last = tpos[:, -1:]
@@ -203,13 +210,13 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
         k_cache = cache["k"].at[b2, tpos].set(k_cd)
         v_cache = cache["v"].at[b2, tpos].set(v_cd)
         valid = jnp.arange(cache_size)[None, None, :] < (tpos[:, :, None] + 1)
-        o = _grouped_attend(q, k_cache, v_cache, valid)
+        o = _grouped_attend(q, k_cache, v_cache, valid, softmax_scale(cfg))
     out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     return out, {"k": k_cache, "v": v_cache}
 
 
 def _grouped_attend(q: jax.Array, keys: jax.Array, vals: jax.Array,
-                    valid: jax.Array) -> jax.Array:
+                    valid: jax.Array, scale: float) -> jax.Array:
     """q (B,C,Hq,hd) against keys/vals (B,K,Hkv,hd) under valid (B,C,K):
     decode_attention_ref's grouped contraction for C queries at once (query
     head h reads KV head h // G), with no per-query-head copy of the keys."""
@@ -217,7 +224,7 @@ def _grouped_attend(q: jax.Array, keys: jax.Array, vals: jax.Array,
     hkv = keys.shape[2]
     qg = q.astype(keys.dtype).reshape(b, c, hkv, hq // hkv, hd)
     logits = jnp.einsum("bqhgd,bkhd->bqhgk", qg, keys,
-                        preferred_element_type=jnp.float32) * hd ** -0.5
+                        preferred_element_type=jnp.float32) * scale
     logits = jnp.where(valid[:, :, None, None, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bqhgk,bkhd->bqhgd", probs.astype(vals.dtype), vals,
@@ -252,7 +259,7 @@ def gqa_decode_stacked(p: Params, x: jax.Array, stacked: dict, g: int,
     k_st = stacked["k"].at[g, bidx, slot].set(k.astype(stacked["k"].dtype))
     v_st = stacked["v"].at[g, bidx, slot].set(v.astype(stacked["v"].dtype))
     o = ops.decode_attention(q, k_st[g], v_st[g], eff_len.astype(jnp.int32),
-                             use_kernel=cfg.use_kernels)
+                             scale=softmax_scale(cfg), use_kernel=cfg.use_kernels)
     out = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None, :]
     new = dict(stacked, k=k_st, v=v_st)
     return out, new

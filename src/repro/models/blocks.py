@@ -8,9 +8,13 @@ with mixer in {global, local, mla, mamba2, mlstm, slstm} and ffn in
 jax.lax.scan over stacked group params -- compact HLO so the 512-device
 dry-run compiles on CPU in reasonable time.
 
-Zamba2's weight-TIED shared attention block is applied after each group of
-`shared_attn_every` mamba layers; its params live outside the scan stack
-(closure), while its per-invocation KV caches are stacked per group.
+Zamba2 (family "hybrid"): before each mamba2 layer listed in
+`hybrid_layer_ids`, one of `n_shared_blocks` weight-shared attention+MLP
+blocks (in turn) reads [x ; token embedding] and its per-invocation linear
+feeds that layer's input: x <- x + Mamba2(norm(x + t)).  The shared blocks'
+params live outside the scan stacks (closure, indexed per group); each
+group of a hybrid phase starts with an invocation, whose adapter, linear
+and KV cache are stacked per group.
 """
 from __future__ import annotations
 
@@ -38,7 +42,34 @@ class Phase:
     kinds: tuple          # mixer kind per slot in the group
     ffns: tuple           # ffn kind per slot
     n_groups: int
-    shared_attn: bool = False   # zamba2: tied attention block after each group
+    # zamba2: each group starts with shared-block invocation
+    # first_invocation + g, which feeds slot0's input
+    hybrid: bool = False
+    first_invocation: int = 0
+
+
+def _hybrid_plan(cfg: ArchConfig) -> list[Phase]:
+    """Mamba2 layers with shared-block invocations before hybrid_layer_ids:
+    the layers before the first invocation are one phase of one-layer
+    groups; then each invocation opens a group that runs to the next one,
+    and consecutive groups of one length share a phase.  A config cut to
+    fewer layers keeps the invocations that fall inside them."""
+    L = cfg.n_layers
+    assert list(cfg.hybrid_layer_ids) == sorted(set(cfg.hybrid_layer_ids)) \
+        and min(cfg.hybrid_layer_ids, default=0) >= 0, cfg.hybrid_layer_ids
+    ids = tuple(i for i in cfg.hybrid_layer_ids if i < L)
+    lead = ids[0] if ids else L
+    phases = [Phase(("mamba2",), ("none",), lead)] if lead else []
+    lengths = [b - a for a, b in zip(ids, ids[1:] + (L,))]
+    k = 0
+    while k < len(lengths):
+        n = 1
+        while k + n < len(lengths) and lengths[k + n] == lengths[k]:
+            n += 1
+        phases.append(Phase(("mamba2",) * lengths[k], ("none",) * lengths[k], n,
+                            hybrid=True, first_invocation=k))
+        k += n
+    return phases
 
 
 def build_plan(cfg: ArchConfig) -> list[Phase]:
@@ -50,12 +81,7 @@ def build_plan(cfg: ArchConfig) -> list[Phase]:
         assert L % per == 0, "xlstm layer count must tile the sLSTM period"
         return [Phase(kinds, ("none",) * per, L // per)]
     if cfg.family == "hybrid":                       # zamba2
-        per = cfg.shared_attn_every
-        full, rem = divmod(L, per)
-        phases = [Phase(("mamba2",) * per, ("none",) * per, full, shared_attn=True)]
-        if rem:
-            phases.append(Phase(("mamba2",) * rem, ("none",) * rem, 1))
-        return phases
+        return _hybrid_plan(cfg)
     ffn = "moe" if cfg.n_experts else "mlp"
     pattern = cfg.block_pattern
     phases = []
@@ -74,10 +100,14 @@ def build_plan(cfg: ArchConfig) -> list[Phase]:
 # ---------------------------------------------------------------------------
 # MLP
 # ---------------------------------------------------------------------------
+GATED_ACTS = {"swiglu": jax.nn.silu,
+              "geglu": lambda g: jax.nn.gelu(g, approximate=False)}
+
+
 def mlp_init(key, cfg: ArchConfig, dtype) -> Params:
     d, f = cfg.d_model, cfg.d_ff
     ks = nn.split_keys(key, 3)
-    if cfg.mlp_act == "swiglu":
+    if cfg.mlp_act in GATED_ACTS:
         return {"w_gate": nn.dense_init(ks[0], (d, f), dtype=dtype),
                 "w_up": nn.dense_init(ks[1], (d, f), dtype=dtype),
                 "w_down": nn.dense_init(ks[2], (f, d), fan_in=f, dtype=dtype)}
@@ -85,14 +115,66 @@ def mlp_init(key, cfg: ArchConfig, dtype) -> Params:
             "w_down": nn.dense_init(ks[1], (f, d), fan_in=f, dtype=dtype)}
 
 
-def mlp_forward(p: Params, x: jax.Array, cfg: ArchConfig) -> jax.Array:
+def mlp_forward(p: Params, x: jax.Array, cfg: ArchConfig,
+                adapter: Optional[Params] = None) -> jax.Array:
+    """act(gate) * up, or act(up) ungated, then down.  `adapter` (a zamba2
+    invocation's params) adds its rank-r LoRA to gate and up."""
+    lora = adapter is not None and cfg.adapter_rank > 0
     h = jnp.einsum("...d,df->...f", x, p["w_up"])
-    if cfg.mlp_act == "swiglu":
-        h = nn.swiglu(h, jnp.einsum("...d,df->...f", x, p["w_gate"]))
+    if lora:
+        a = jnp.einsum("...d,dr->...r", x, adapter["adapter_in"])
+        h = h + jnp.einsum("...r,rf->...f", a, adapter["adapter_up"])
+    if cfg.mlp_act in GATED_ACTS:
+        g = jnp.einsum("...d,df->...f", x, p["w_gate"])
+        if lora:
+            g = g + jnp.einsum("...r,rf->...f", a, adapter["adapter_gate"])
+        h = GATED_ACTS[cfg.mlp_act](g) * h
     else:
         h = nn.ACTIVATIONS[cfg.mlp_act](h)
     h = constrain(h, "batch", None, "model")
     return jnp.einsum("...f,fd->...d", h, p["w_down"])
+
+
+# ---------------------------------------------------------------------------
+# Zamba2 shared blocks
+# ---------------------------------------------------------------------------
+def shared_init(key, cfg: ArchConfig, dtype) -> Params:
+    """One weight-shared attention+MLP block: attention from the
+    2*d_model-wide [x ; embedding] back to d_model, then the MLP."""
+    ks = nn.split_keys(key, 2)
+    return {"norm1": jnp.zeros((cfg.shared_in,), dtype),
+            "mixer": attn.gqa_init(ks[0], cfg, dtype, d_in=cfg.shared_in),
+            "norm2": jnp.zeros((cfg.d_model,), dtype),
+            "ffn": mlp_init(ks[1], cfg, dtype)}
+
+
+def invocation_init(key, cfg: ArchConfig, dtype) -> Params:
+    """What one invocation of a shared block owns: the linear into the next
+    mamba layer's input, and the MLP adapter when adapter_rank > 0."""
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    ks = nn.split_keys(key, 4)
+    p = {"linear": nn.dense_init(ks[0], (d, d), dtype=dtype)}
+    if r:
+        p["adapter_in"] = nn.dense_init(ks[1], (d, r), dtype=dtype)
+        p["adapter_gate"] = nn.dense_init(ks[2], (r, f), dtype=dtype)
+        p["adapter_up"] = nn.dense_init(ks[3], (r, f), dtype=dtype)
+    return p
+
+
+def shared_block(sp: Params, ip: Params, x: jax.Array, emb: jax.Array,
+                 cfg: ArchConfig, attend):
+    """One invocation: t = linear(MLP_k(norm2(attn(norm1([x ; emb]))))),
+    with no residual inside.  `attend(mixer params, normed input)` returns
+    (attention output, its cache).  Returns (t, cache)."""
+    with jax.named_scope("shared_block"):
+        s = nn.rms_norm(jnp.concatenate([x, emb], axis=-1), sp["norm1"],
+                        cfg.norm_eps)
+        with jax.named_scope("attention"):
+            a, cache = attend(sp["mixer"], s)
+        h = nn.rms_norm(a, sp["norm2"], cfg.norm_eps)
+        with jax.named_scope("ffn"):
+            m = mlp_forward(sp["ffn"], h, cfg, adapter=ip)
+        return jnp.einsum("...d,de->...e", m, ip["linear"]), cache
 
 
 # ---------------------------------------------------------------------------
@@ -151,10 +233,16 @@ def _mixer_decode(p, x, cache, positions, cfg, kind):
     return dec(p, x, cache, cfg)
 
 
+def _norm_in(p: Params, x: jax.Array, cfg: ArchConfig, shared):
+    """norm1 of the mixer's input: x, or x + t after a zamba2 shared block
+    (t feeds the mixer only, not the residual)."""
+    return nn.rms_norm(x if shared is None else x + shared, p["norm1"], cfg.norm_eps)
+
+
 def slot_forward(p: Params, x: jax.Array, positions, cfg: ArchConfig,
                  kind: str, ffn: str, *, collect_cache: bool = False,
-                 enc_kv=None):
-    mix_out, cache = _mixer_forward(p["mixer"], nn.rms_norm(x, p["norm1"], cfg.norm_eps),
+                 enc_kv=None, shared=None):
+    mix_out, cache = _mixer_forward(p["mixer"], _norm_in(p, x, cfg, shared),
                                     positions, cfg, kind, collect_cache)
     x = x + mix_out
     if enc_kv is not None:
@@ -179,8 +267,8 @@ def mixer_scope(kind: str) -> str:
 
 
 def slot_decode(p: Params, x: jax.Array, cache, positions, cfg: ArchConfig,
-                kind: str, ffn: str, *, enc_kv=None):
-    xn = nn.rms_norm(x, p["norm1"], cfg.norm_eps)
+                kind: str, ffn: str, *, enc_kv=None, shared=None):
+    xn = _norm_in(p, x, cfg, shared)
     with jax.named_scope(mixer_scope(kind)):
         mix_out, new_cache = _mixer_decode(p["mixer"], xn, cache, positions, cfg, kind)
     x = x + mix_out
@@ -208,13 +296,13 @@ def _mixer_prefill(p, x, cache, positions, cfg, kind):
 
 
 def slot_prefill(p: Params, x: jax.Array, cache, positions, cfg: ArchConfig,
-                 kind: str, ffn: str):
+                 kind: str, ffn: str, *, shared=None):
     """Chunked-prefill twin of slot_decode: C tokens, decode-cache layout.
 
     Attention kinds batch all C queries against the cache with decode-exact
-    masking; recurrent kinds scan the exact decode recurrence.  FFN / norms
+    masking; recurrent kinds carry their state across the chunk.  FFN / norms
     are position-independent row ops and run batched."""
-    xn = nn.rms_norm(x, p["norm1"], cfg.norm_eps)
+    xn = _norm_in(p, x, cfg, shared)
     with jax.named_scope(mixer_scope(kind)):
         mix_out, new_cache = _mixer_prefill(p["mixer"], xn, cache, positions, cfg, kind)
     x = x + mix_out

@@ -53,14 +53,18 @@ def init_params(key, cfg: ArchConfig) -> Params:
         pk = nn.split_keys(keys[2 + pi], phase.n_groups)
         groups = []
         for g in range(phase.n_groups):
-            gk = nn.split_keys(pk[g], len(phase.kinds))
-            groups.append({
+            gk = nn.split_keys(pk[g], len(phase.kinds) + 1)
+            group = {
                 f"slot{j}": blocks.slot_init(gk[j], cfg, kind, ffn, dtype, cross=cross)
                 for j, (kind, ffn) in enumerate(zip(phase.kinds, phase.ffns))
-            })
+            }
+            if phase.hybrid:            # this group's shared-block invocation
+                group["hybrid"] = blocks.invocation_init(gk[-1], cfg, dtype)
+            groups.append(group)
         p[f"phase{pi}"] = nn.stack_layers(groups)
-    if cfg.family == "hybrid":          # zamba2 tied shared attn+MLP block
-        p["shared"] = blocks.slot_init(keys[-2], cfg, "global", "mlp", dtype)
+    if cfg.family == "hybrid":          # zamba2 weight-shared attn+MLP blocks
+        sk = nn.split_keys(keys[-2], cfg.n_shared_blocks)
+        p["shared"] = nn.stack_layers([blocks.shared_init(k, cfg, dtype) for k in sk])
     if cfg.family == "audio":           # whisper encoder stack
         ek = nn.split_keys(keys[-1], cfg.encoder_layers)
         p["encoder"] = nn.stack_layers([
@@ -105,6 +109,25 @@ def _encoder(params, cfg: ArchConfig, frames):
     return x
 
 
+def _group_ids(phase):
+    """The scan's group index, which a hybrid phase needs to pick its
+    shared block; None (nothing scanned) elsewhere."""
+    return jnp.arange(phase.n_groups) if phase.hybrid else None
+
+
+def _shared_params(params, cfg: ArchConfig, phase, g):
+    """Params of the shared block that group g of a hybrid phase invokes:
+    block (first_invocation + g) mod n_shared_blocks, in turn."""
+    b = (phase.first_invocation + g) % cfg.n_shared_blocks
+    return jax.tree_util.tree_map(lambda a: a[b], params["shared"])
+
+
+def _cached_window(cfg: ArchConfig, kv: dict) -> int:
+    """Window of a shared block's cache: windowed iff it was built as a ring
+    (size at most the window, see _shared_window)."""
+    return cfg.sliding_window if kv["k"].shape[1] <= cfg.sliding_window else 0
+
+
 def _positions_for(cfg: ArchConfig, batch) -> jax.Array:
     tokens = batch["tokens"]
     if cfg.use_mrope:
@@ -137,43 +160,49 @@ def forward(params, cfg: ArchConfig, batch, *, collect_cache: bool = False,
     params = nn.cast_tree(params, cfg.compute_dtype)   # mixed precision
     plan = blocks.build_plan(cfg)
     positions = _positions_for(cfg, batch)
-    x = _inputs(params, cfg, batch)
+    x = emb = _inputs(params, cfg, batch)
     enc_out = _encoder(params, cfg, batch["frames"]) if cfg.family == "audio" else None
     aux = jnp.zeros((), jnp.float32)
     caches: dict = {}
+    w = _shared_window(cfg, cache_len)
 
     for pi, phase in enumerate(plan):
         stacked = params[f"phase{pi}"]
 
-        def group_fn(carry, gp, phase=phase):
+        def group_fn(carry, xs, phase=phase):
             h, a = carry
+            gp, g = xs
             gcache = {}
+            shared = None
+            if phase.hybrid:
+                def attend(mp, s):
+                    out, (k, v) = attn.gqa_forward(mp, s, positions, cfg,
+                                                   window=w, return_kv=True)
+                    return out, {"k": k, "v": v}
+                shared, c = blocks.shared_block(_shared_params(params, cfg, phase, g),
+                                                gp["hybrid"], h, emb, cfg, attend)
+                if collect_cache:
+                    kind = "local" if w else "global"
+                    gcache["shared"] = _pad_cache(c, kind, cfg, cache_len, window=w)
             for j, (kind, ffn) in enumerate(zip(phase.kinds, phase.ffns)):
                 enc_kv = None
                 if enc_out is not None:
                     enc_kv = attn.cross_kv(gp[f"slot{j}"]["cross"], enc_out)
                 h, c, aj = blocks.slot_forward(
                     gp[f"slot{j}"], h, positions, cfg, kind, ffn,
-                    collect_cache=collect_cache, enc_kv=enc_kv)
+                    collect_cache=collect_cache, enc_kv=enc_kv,
+                    shared=shared if j == 0 else None)
                 if collect_cache:
                     c = _pad_cache(c, kind, cfg, cache_len)
                     if enc_kv is not None:
                         c = dict(c, cross_k=enc_kv[0], cross_v=enc_kv[1])
                     gcache[f"slot{j}"] = c
                 a = a + aj
-            if phase.shared_attn:
-                w = _shared_window(cfg, cache_len)
-                kind = "local" if w else "global"
-                h, c, _ = blocks.slot_forward(
-                    params["shared"], h, positions, cfg, kind, "mlp",
-                    collect_cache=collect_cache)
-                if collect_cache:
-                    gcache["shared"] = _pad_cache(c, kind, cfg, cache_len, window=w)
             h = constrain(h, "batch", None, None)
             return (h, a), (gcache if collect_cache else None)
 
         body = jax.checkpoint(group_fn) if cfg.remat else group_fn
-        (x, aux), pc = jax.lax.scan(body, (x, aux), stacked,
+        (x, aux), pc = jax.lax.scan(body, (x, aux), (stacked, _group_ids(phase)),
                                     unroll=True if cfg.scan_unroll else 1)
         if collect_cache:
             caches[f"phase{pi}"] = pc
@@ -238,7 +267,7 @@ def decode_step(params, cfg: ArchConfig, tokens, positions, cache):
     better-measured baseline and is kept."""
     params = nn.cast_tree(params, cfg.compute_dtype)   # mixed precision
     plan = blocks.build_plan(cfg)
-    x = _embed(params, cfg, tokens)
+    x = emb = _embed(params, cfg, tokens)
     if cfg.family == "audio":
         x = x + sinusoid(positions[:, None], cfg.d_model).astype(x.dtype)
     x = constrain(x, "batch", None, None)
@@ -249,29 +278,29 @@ def decode_step(params, cfg: ArchConfig, tokens, positions, cache):
         pcache = cache[f"phase{pi}"]
 
         def group_fn(h, xs, phase=phase):
-            gp, gc = xs
+            gp, gc, g = xs
             out_c = {}
+            shared = None
+            if phase.hybrid:
+                w = _cached_window(cfg, gc["shared"])
+                shared, out_c["shared"] = blocks.shared_block(
+                    _shared_params(params, cfg, phase, g), gp["hybrid"], h, emb, cfg,
+                    lambda mp, s: attn.gqa_decode(mp, s, gc["shared"], positions,
+                                                  cfg, window=w))
             for j, (kind, ffn) in enumerate(zip(phase.kinds, phase.ffns)):
                 sc = dict(gc[f"slot{j}"])
                 enc_kv = None
                 if cfg.family == "audio":
                     enc_kv = (sc.pop("cross_k"), sc.pop("cross_v"))
                 h, nc = blocks.slot_decode(gp[f"slot{j}"], h, sc, positions, cfg,
-                                           kind, ffn, enc_kv=enc_kv)
+                                           kind, ffn, enc_kv=enc_kv,
+                                           shared=shared if j == 0 else None)
                 if enc_kv is not None:
                     nc = dict(nc, cross_k=enc_kv[0], cross_v=enc_kv[1])
                 out_c[f"slot{j}"] = nc
-            if phase.shared_attn:
-                # window iff the cache was built windowed (ring size < budget)
-                w = cfg.sliding_window if gc["shared"]["k"].shape[1] <= cfg.sliding_window \
-                    else 0
-                kind = "local" if w else "global"
-                h, nc = blocks.slot_decode(params["shared"], h, gc["shared"],
-                                           positions, cfg, kind, "mlp")
-                out_c["shared"] = nc
             return h, out_c
 
-        x, pc = jax.lax.scan(group_fn, x, (stacked, pcache),
+        x, pc = jax.lax.scan(group_fn, x, (stacked, pcache, _group_ids(phase)),
                              unroll=True if cfg.scan_unroll else 1)
         new_cache[f"phase{pi}"] = pc
 
@@ -291,7 +320,7 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, positions, cache):
     decode_step's, but each slot consumes the whole chunk -- attention kinds
     write their C cache rows and attend with decode-exact masking
     (attention.gqa_prefill / mla_prefill), recurrent kinds scan the exact
-    decode recurrence (ssm.*_prefill).  A P-token prompt therefore costs
+    recurrence across the chunk (ssm.*_prefill).  A P-token prompt costs
     O(P/C) calls instead of P decode steps, and the oracle suite
     (tests/test_prefill_oracle.py) pins its outputs to the teacher-forced
     decode_step reference."""
@@ -299,7 +328,7 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, positions, cache):
         raise NotImplementedError("chunked prefill: audio enc-dec unsupported")
     params = nn.cast_tree(params, cfg.compute_dtype)   # mixed precision
     plan = blocks.build_plan(cfg)
-    x = _embed(params, cfg, tokens)
+    x = emb = _embed(params, cfg, tokens)
     x = constrain(x, "batch", None, None)
     new_cache: dict = {}
 
@@ -308,22 +337,23 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, positions, cache):
         pcache = cache[f"phase{pi}"]
 
         def group_fn(h, xs, phase=phase):
-            gp, gc = xs
+            gp, gc, g = xs
             out_c = {}
+            shared = None
+            if phase.hybrid:
+                w = _cached_window(cfg, gc["shared"])
+                shared, out_c["shared"] = blocks.shared_block(
+                    _shared_params(params, cfg, phase, g), gp["hybrid"], h, emb, cfg,
+                    lambda mp, s: attn.gqa_prefill(mp, s, gc["shared"], positions,
+                                                   cfg, window=w))
             for j, (kind, ffn) in enumerate(zip(phase.kinds, phase.ffns)):
                 h, nc = blocks.slot_prefill(gp[f"slot{j}"], h, gc[f"slot{j}"],
-                                            positions, cfg, kind, ffn)
+                                            positions, cfg, kind, ffn,
+                                            shared=shared if j == 0 else None)
                 out_c[f"slot{j}"] = nc
-            if phase.shared_attn:
-                w = cfg.sliding_window if gc["shared"]["k"].shape[1] <= cfg.sliding_window \
-                    else 0
-                kind = "local" if w else "global"
-                h, nc = blocks.slot_prefill(params["shared"], h, gc["shared"],
-                                            positions, cfg, kind, "mlp")
-                out_c["shared"] = nc
             return h, out_c
 
-        x, pc = jax.lax.scan(group_fn, x, (stacked, pcache),
+        x, pc = jax.lax.scan(group_fn, x, (stacked, pcache, _group_ids(phase)),
                              unroll=True if cfg.scan_unroll else 1)
         new_cache[f"phase{pi}"] = pc
 
@@ -347,7 +377,7 @@ def init_cache(cfg: ArchConfig, batch: int, length: int) -> Any:
                 c["cross_k"] = jnp.zeros((phase.n_groups, batch, cfg.encoder_len, hkv, hd), cdt)
                 c["cross_v"] = jnp.zeros((phase.n_groups, batch, cfg.encoder_len, hkv, hd), cdt)
             pc[f"slot{j}"] = c
-        if phase.shared_attn:
+        if phase.hybrid:
             w = _shared_window(cfg, length)
             shp = blocks.slot_cache_shape(
                 cfg, "local" if w else "global", batch, length)

@@ -29,106 +29,133 @@ Params = Any
 # Mamba2 (SSD)
 # ===========================================================================
 def mamba2_init(key, cfg: ArchConfig, dtype) -> Params:
+    """Mamba2 mixer params.  A and dt_bias follow Mamba2's published
+    initialisation: A uniform in [1, 16], dt log-uniform in [1e-3, 1e-1]
+    stored through the inverse softplus."""
     d, inner, n, kconv = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_conv
-    h = cfg.n_heads                       # ssm heads; head dim P = inner // h
-    ks = nn.split_keys(key, 6)
-    conv_dim = inner + 2 * n              # x, B, C all pass the causal conv
+    h = cfg.ssm_heads
+    ks = nn.split_keys(key, 7)
+    conv_dim = inner + 2 * cfg.ssm_groups * n     # x, B, C all pass the conv
+    dt = jnp.exp(jax.random.uniform(ks[5], (h,), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
     return {
         "in_proj": nn.dense_init(ks[0], (d, inner), fan_in=d, dtype=dtype),      # gate z
         "xbc_proj": nn.dense_init(ks[1], (d, conv_dim), fan_in=d, dtype=dtype),
         "conv_w": nn.dense_init(ks[2], (kconv, conv_dim), fan_in=kconv, dtype=dtype),
         "conv_b": jnp.zeros((conv_dim,), dtype),
         "dt_proj": nn.dense_init(ks[3], (d, h), fan_in=d, dtype=dtype),
-        "dt_bias": jnp.zeros((h,), dtype),
-        "A_log": jnp.log(jnp.linspace(1.0, 16.0, h)).astype(jnp.float32),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "A_log": jnp.log(jax.random.uniform(ks[6], (h,), jnp.float32, 1.0, 16.0)),
         "D_skip": jnp.ones((h,), dtype),
         "ssm_norm": jnp.zeros((inner,), dtype),
         "out_proj": nn.dense_init(ks[4], (inner, d), fan_in=inner, dtype=dtype),
     }
 
 
-def _causal_conv(u: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """Depthwise causal conv1d. u: (B,S,C), w: (k,C)."""
-    k = w.shape[0]
-    up = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
-    out = sum(up[:, i:i + u.shape[1], :] * w[i][None, None, :] for i in range(k))
-    return out + b
+def _mamba2_in(p: Params, x: jax.Array):
+    """Projections of x (B,S,D), all in f32: gate z, the conv's input xBC,
+    and dt = softplus(x W_dt + dt_bias) (no clamp)."""
+    f32 = jnp.float32
+    z = jnp.einsum("bsd,di->bsi", x, p["in_proj"], preferred_element_type=f32)
+    xbc = jnp.einsum("bsd,dc->bsc", x, p["xbc_proj"], preferred_element_type=f32)
+    dt = jax.nn.softplus(jnp.einsum("bsd,dh->bsh", x, p["dt_proj"],
+                                    preferred_element_type=f32)
+                         + p["dt_bias"].astype(f32))
+    return z, xbc, dt
+
+
+def _mamba2_split(xbc: jax.Array, cfg: ArchConfig):
+    """Post-conv xBC (...,C) -> x (...,H,P), B (...,G,N), C (...,G,N)."""
+    inner, n, g = cfg.d_inner, cfg.ssm_state, cfg.ssm_groups
+    xin, Bm, Cm = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+    lead = xbc.shape[:-1]
+    return (xin.reshape(lead + (cfg.ssm_heads, cfg.ssm_head_dim)),
+            Bm.reshape(lead + (g, n)), Cm.reshape(lead + (g, n)))
+
+
+def _mamba2_out(p: Params, y: jax.Array, xin: jax.Array, z: jax.Array,
+                cfg: ArchConfig):
+    """y, xin (...,H,P) f32 -> + D x, RMSNorm over each group of heads of
+    y * silu(z), then out_proj."""
+    y = y + xin * p["D_skip"].astype(jnp.float32)[:, None]
+    lead, g = y.shape[:-2], cfg.ssm_groups
+    y = y.reshape(lead + (cfg.d_inner,)) * jax.nn.silu(z)
+    y = nn.rms_norm(y.reshape(lead + (g, cfg.d_inner // g)),
+                    p["ssm_norm"].reshape(g, -1), cfg.norm_eps)
+    y = y.reshape(lead + (cfg.d_inner,)).astype(p["out_proj"].dtype)
+    return jnp.einsum("...i,id->...d", y, p["out_proj"])
+
+
+def _conv_silu(p: Params, full: jax.Array, s: int) -> jax.Array:
+    """silu of the causal depthwise conv (with bias) of the last s of
+    `full` (B,k-1+s,C), in f32: the sum of k products cancels, and bf16
+    rounding of its terms would come out relatively large."""
+    w = p["conv_w"].astype(jnp.float32)
+    conv = sum(full[:, i:i + s] * w[i] for i in range(w.shape[0]))
+    return jax.nn.silu(conv + p["conv_b"].astype(jnp.float32))
+
+
+def _mamba2_seq(p: Params, x: jax.Array, cfg: ArchConfig, state=None):
+    """S tokens at once from `state` (None: zero state, the start of a
+    sequence): projections, causal conv and gating batched over the
+    tokens, the state recurrence in the chunked SSD form.  Returns
+    (out (B,S,D), final state)."""
+    b, s, _ = x.shape
+    k = cfg.ssm_conv
+    z, xbc, dt = _mamba2_in(p, x)
+    tail = (jnp.zeros((b, k - 1, xbc.shape[-1]), jnp.float32) if state is None
+            else state["conv"])
+    full = jnp.concatenate([tail, xbc], axis=1)                     # (B,k-1+S,C)
+    xin, Bm, Cm = _mamba2_split(_conv_silu(p, full, s), cfg)
+    xin = constrain(xin, "batch", None, "model", None)
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    chunk_unroll = cfg.chunk_unroll if cfg.chunk_unroll is not None \
+        else cfg.scan_unroll
+    y, h = ops.ssm_scan(xin, dt, A, Bm, Cm, chunk=cfg.ssm_chunk,
+                        h0=None if state is None else state["ssm"],
+                        use_kernel=cfg.use_kernels, unroll=chunk_unroll)
+    out = _mamba2_out(p, y, xin, z, cfg)
+    return out, {"ssm": h, "conv": full[:, s:]}
 
 
 def mamba2_forward(p: Params, x: jax.Array, cfg: ArchConfig,
                    *, return_state: bool = False):
-    b, s, d = x.shape
-    inner, n, h = cfg.d_inner, cfg.ssm_state, cfg.n_heads
-    ph = inner // h
-    z = jnp.einsum("bsd,di->bsi", x, p["in_proj"])
-    xbc = jnp.einsum("bsd,dc->bsc", x, p["xbc_proj"])
-    xbc = jax.nn.silu(_causal_conv(xbc, p["conv_w"], p["conv_b"]))
-    xin, Bm, Cm = jnp.split(xbc, [inner, inner + n], axis=-1)
-    xin = constrain(xin, "batch", None, "model")
-    dt = jax.nn.softplus(jnp.einsum("bsd,dh->bsh", x, p["dt_proj"])
-                         + p["dt_bias"].astype(x.dtype))
-    A = -jnp.exp(p["A_log"])
-    chunk_unroll = cfg.chunk_unroll if cfg.chunk_unroll is not None else cfg.scan_unroll
-    y, h_final = ops.ssm_scan(xin.reshape(b, s, h, ph), dt, A, Bm, Cm,
-                              chunk=cfg.ssm_chunk, use_kernel=cfg.use_kernels,
-                              unroll=chunk_unroll)
-    y = y.reshape(b, s, inner) + xin * jnp.repeat(p["D_skip"], ph)[None, None, :]
-    y = nn.rms_norm(y * jax.nn.silu(z), p["ssm_norm"], cfg.norm_eps)
-    out = jnp.einsum("bsi,id->bsd", y, p["out_proj"])
-    if return_state:
-        # conv tail: last (k-1) pre-activation conv inputs
-        k = cfg.ssm_conv
-        xbc_raw = jnp.einsum("bsd,dc->bsc", x, p["xbc_proj"])
-        tail = xbc_raw[:, -(k - 1):, :] if s >= k - 1 else jnp.pad(
-            xbc_raw, ((0, 0), (k - 1 - s, 0), (0, 0)))
-        return out, {"ssm": h_final, "conv": tail.astype(jnp.float32)}
-    return out
+    out, state = _mamba2_seq(p, x, cfg)
+    return (out, state) if return_state else out
 
 
 def mamba2_decode(p: Params, x: jax.Array, cache: dict, cfg: ArchConfig):
-    """x: (B,1,D); cache {ssm:(B,H,P,N) f32, conv:(B,k-1,convdim) f32}."""
-    b = x.shape[0]
-    inner, n, h, k = cfg.d_inner, cfg.ssm_state, cfg.n_heads, cfg.ssm_conv
-    ph = inner // h
-    z = jnp.einsum("bsd,di->bsi", x, p["in_proj"])[:, 0]
-    xbc_new = jnp.einsum("bsd,dc->bsc", x, p["xbc_proj"])[:, 0]    # (B,C)
-    window = jnp.concatenate([cache["conv"], xbc_new[:, None, :].astype(jnp.float32)], axis=1)
-    conv_out = jnp.einsum("bkc,kc->bc", window.astype(x.dtype), p["conv_w"]) + p["conv_b"]
-    xbc = jax.nn.silu(conv_out)
-    xin, Bm, Cm = jnp.split(xbc, [inner, inner + n], axis=-1)
-    dt = jax.nn.softplus(jnp.einsum("bsd,dh->bsh", x, p["dt_proj"])[:, 0]
-                         + p["dt_bias"].astype(x.dtype))           # (B,H)
-    A = -jnp.exp(p["A_log"])
-    hstate = cache["ssm"]
-    decay = jnp.exp(A[None, :] * dt.astype(jnp.float32))           # (B,H)
-    inject = jnp.einsum("bh,bhp,bn->bhpn", dt.astype(jnp.float32),
-                        xin.reshape(b, h, ph).astype(jnp.float32),
-                        Bm.astype(jnp.float32))
+    """x: (B,1,D); cache {ssm:(B,H,P,N) f32, conv:(B,k-1,convdim) f32}.
+    Head h reads B/C group h // (H // G)."""
+    b, g = x.shape[0], cfg.ssm_groups
+    z, xbc_new, dt = _mamba2_in(p, x)
+    window = jnp.concatenate([cache["conv"], xbc_new], axis=1)
+    xin, Bm, Cm = _mamba2_split(_conv_silu(p, window, 1)[:, 0], cfg)  # (B,H,P),(B,G,N)
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))
+    dt = dt[:, 0]                                                  # (B,H)
+    hstate = cache["ssm"].reshape(b, g, cfg.ssm_heads // g, cfg.ssm_head_dim, -1)
+    xg = xin.reshape(hstate.shape[:-1])
+    dtg = dt.reshape(b, g, -1)
+    decay = jnp.exp(A.reshape(g, -1)[None] * dtg)                  # (B,G,Hg)
+    inject = jnp.einsum("bgh,bghp,bgn->bghpn", dtg, xg, Bm)
     hstate = hstate * decay[..., None, None] + inject
-    y = jnp.einsum("bhpn,bn->bhp", hstate, Cm.astype(jnp.float32)).astype(x.dtype)
-    y = y.reshape(b, inner) + xin * jnp.repeat(p["D_skip"], ph)[None, :]
-    y = nn.rms_norm(y * jax.nn.silu(z), p["ssm_norm"], cfg.norm_eps)
-    out = jnp.einsum("bi,id->bd", y, p["out_proj"])[:, None, :]
-    return out, {"ssm": hstate, "conv": window[:, 1:, :]}
+    y = jnp.einsum("bghpn,bgn->bghp", hstate, Cm)
+    out = _mamba2_out(p, y.reshape(xin.shape), xin, z[:, 0], cfg)[:, None, :]
+    return out, {"ssm": hstate.reshape(cache["ssm"].shape), "conv": window[:, 1:, :]}
 
 
 def mamba2_prefill(p: Params, x: jax.Array, cache: dict, cfg: ArchConfig):
-    """Chunk prefill: scan the exact decode recurrence over C tokens.
-
-    Bit-identical to C successive `mamba2_decode` calls (the chunkwise-
-    parallel `mamba2_forward` is NOT -- different reduction order)."""
-    def step(carry, xt):                                           # xt: (B,D)
-        out, new = mamba2_decode(p, xt[:, None, :], carry, cfg)
-        return new, out[:, 0]
-
-    carry, ys = jax.lax.scan(step, cache, x.transpose(1, 0, 2))
-    return ys.transpose(1, 0, 2), carry
+    """Chunk prefill: C tokens against the carried state, the projections,
+    conv and gating batched over the chunk and the recurrence in the
+    chunked SSD form.  Agrees with C successive `mamba2_decode` calls to
+    rounding (another order of the same sums), not bit for bit."""
+    return _mamba2_seq(p, x, cfg, state=cache)
 
 
 def mamba2_cache_shape(cfg: ArchConfig, batch: int):
-    inner, n, h = cfg.d_inner, cfg.ssm_state, cfg.n_heads
-    return {"ssm": (batch, h, inner // h, n),
-            "conv": (batch, cfg.ssm_conv - 1, inner + 2 * n)}
+    inner, n = cfg.d_inner, cfg.ssm_state
+    return {"ssm": (batch, cfg.ssm_heads, cfg.ssm_head_dim, n),
+            "conv": (batch, cfg.ssm_conv - 1, inner + 2 * cfg.ssm_groups * n)}
 
 
 # ===========================================================================

@@ -106,6 +106,10 @@ def test_full_configs_match_assignment_sheet():
         "zamba2_1_2b": dict(n_layers=38, d_model=2048, n_heads=32,
                             n_kv_heads=32, d_ff=8192, vocab_size=32000,
                             ssm_state=64),
+        "zamba2_7b": dict(n_layers=81, d_model=3584, n_heads=32, n_kv_heads=32,
+                          head_dim=224, d_ff=14336, vocab_size=32000,
+                          ssm_state=64, ssm_heads=112, ssm_groups=2,
+                          n_shared_blocks=2, adapter_rank=128),
     }
     for arch, fields in spec.items():
         cfg = registry.get_config(arch)
@@ -123,7 +127,7 @@ def test_long_context_skip_policy():
         "granite_moe_3b_a800m": False, "xlstm_1_3b": True, "granite_3_8b": False,
         "gemma3_4b": True, "deepseek_v2_lite_16b": False, "h2o_danube_3_4b": True,
         "whisper_base": False, "minitron_4b": False, "qwen2_vl_7b": False,
-        "zamba2_1_2b": True,
+        "zamba2_1_2b": True, "zamba2_7b": True,
     }
 
 

@@ -185,6 +185,28 @@ def test_ssm_scan_extreme_decay_no_nan():
         assert not np.isnan(np.asarray(fn())).any()
 
 
+def test_ops_ssm_scan_routes_only_what_the_kernel_takes():
+    """ops.ssm_scan: the kernel for one group from a zero state; a carried
+    state goes to the chunked jnp path; two groups under the kernel raise."""
+    ks = jax.random.split(KEY, 6)
+    B, s, h, p, n = 1, 32, 4, 8, 16
+    x = jax.random.normal(ks[0], (B, s, h, p)) * 0.5
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, s, h)))
+    A = -jnp.abs(jax.random.normal(ks[2], (h,))) * 4
+    Bm = jax.random.normal(ks[3], (B, s, 1, n)) * 0.3
+    Cm = jax.random.normal(ks[4], (B, s, 1, n)) * 0.3
+    h0 = jax.random.normal(ks[5], (B, h, p, n)) * 0.3
+    y_k, _ = ops.ssm_scan(x, dt, A, Bm, Cm, chunk=16, use_kernel=True)
+    y_j, _ = ops.ssm_scan(x, dt, A, Bm, Cm, chunk=16)
+    np.testing.assert_allclose(y_k, y_j, rtol=2e-4, atol=2e-4)
+    y_kh, _ = ops.ssm_scan(x, dt, A, Bm, Cm, chunk=16, h0=h0, use_kernel=True)
+    y_jh, _ = ops.ssd_chunked_jnp(x, dt, A, Bm, Cm, chunk=16, h0=h0)
+    np.testing.assert_array_equal(y_kh, y_jh)
+    two = jnp.concatenate([Bm, Bm], axis=2)
+    with pytest.raises(ValueError, match="one B/C group"):
+        ops.ssm_scan(x, dt, A, two, two, chunk=16, use_kernel=True)
+
+
 @pytest.mark.parametrize("sq,skv,hq,hkv,window,off", [
     (64, 64, 4, 2, 0, 0), (100, 100, 8, 2, 24, 0), (33, 128, 4, 4, 0, 95),
 ])

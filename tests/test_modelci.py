@@ -300,8 +300,10 @@ def _fed_monitor(threshold=1.5, sustain=2, min_n=8, metrics=None):
 
 
 def feed(mon, t, ratio, n=10, _state={}):
-    """One scrape's cumulative counters at observed ratio x profile."""
-    key = id(mon)
+    """One scrape's cumulative counters at observed ratio x profile.
+    Keyed by the monitor itself, which the dict keeps alive: an id() key
+    was reused by a later test's monitor and handed it stale counters."""
+    key = mon
     busy, served = _state.get(key, (0.0, 0))
     busy += ratio * 0.01 * n
     served += n

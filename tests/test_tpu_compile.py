@@ -104,7 +104,7 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, arch):
         f"{kernel} at {arch} widths compiled without its Pallas kernel"
 
 
-def _compile_h2o_step(one_chip, step, cfg):
+def _compile_serving_step(one_chip, step, cfg):
     """lm.decode_step (8 slots, one token each) or lm.prefill_chunk (one
     row, CHUNK tokens) for cfg, compiled for one described v5e."""
     def place(tree):
@@ -129,11 +129,25 @@ def test_h2o_serving_step_fits_one_v5e(one_chip, step):
     """The batcher's two programs for h2o-danube-3-4b at published widths,
     bf16 weights, at chip_smoke.py's slots and length."""
     cfg = registry.get_config("h2o_danube_3_4b").replace(param_dtype="bfloat16")
-    compiled = _compile_h2o_step(one_chip, step, cfg)
+    compiled = _compile_serving_step(one_chip, step, cfg)
     m = compiled.memory_analysis()
     used = m.argument_size_in_bytes + m.output_size_in_bytes \
         + m.temp_size_in_bytes
     assert used < HBM_BYTES, f"{step}: {used / 1e9:.2f} GB"
+
+
+@pytest.mark.parametrize("step", ["decode_step", "prefill_chunk"])
+def test_zamba2_7b_serving_step_fits_one_v5e(one_chip, step):
+    """The batcher's two programs for one zamba2_7b pipeline stage (27 layers,
+    invocations at 6, 11, 17, 23) at published widths, bf16 weights, 8
+    slots x 2048 positions: at most 15 GB, weights, caches and temporaries."""
+    cfg = registry.get_config("zamba2_7b").replace(
+        n_layers=27, hybrid_layer_ids=(6, 11, 17, 23), param_dtype="bfloat16")
+    compiled = _compile_serving_step(one_chip, step, cfg)
+    m = compiled.memory_analysis()
+    used = m.argument_size_in_bytes + m.output_size_in_bytes \
+        + m.temp_size_in_bytes
+    assert used <= 15e9, f"{step}: {used / 1e9:.2f} GB"
 
 
 def test_h2o_decode_step_contracts_grouped_queries_on_v5e(one_chip):
@@ -143,7 +157,7 @@ def test_h2o_decode_step_contracts_grouped_queries_on_v5e(one_chip):
     KV heads lowered to (0.29 GB of temporaries), and almost no temps."""
     cfg = registry.get_config("h2o_danube_3_4b").replace(
         param_dtype="bfloat16", block_pattern=("global",), sliding_window=0)
-    compiled = _compile_h2o_step(one_chip, "decode_step", cfg)
+    compiled = _compile_serving_step(one_chip, "decode_step", cfg)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 64e6, f"decode step temporaries {temp / 1e9:.3f} GB"
     per_head_cache = SLOTS * MAX_LEN * cfg.n_heads * cfg.head_dim
