@@ -87,16 +87,16 @@ def serve_phase(cfg, *, seed: int = 0, max_slots: int = 8, max_len: int = 2048,
     cache_b = _tree_bytes(jax.eval_shape(
         lambda: lm.init_cache(cfg, max_slots, max_len)))
     row_b = _tree_bytes(jax.eval_shape(lambda: lm.init_cache(cfg, 1, max_len)))
-    # decode_step does not donate its cache, so the old and the new cache
-    # coexist; prefill works on a one-row cache of its own
-    need = params_b + 2 * cache_b + row_b
+    # the batcher donates the cache to its decode step, which writes it in
+    # place; prefill works on a one-row cache of its own
+    need = params_b + cache_b + row_b
     stats = dev.memory_stats() or {}
     limit = stats.get("bytes_limit")
     share = f"{need / limit:.1%} of the {limit / GB:.2f} GB limit" if limit \
         else "device reports no limit"
     log(f"serve: max_slots={max_slots} max_len={max_len} "
         f"prefill_chunk={prefill_chunk}, chosen so that params "
-        f"{params_b / GB:.2f} GB + 2 x cache {cache_b / GB:.2f} GB + one-row "
+        f"{params_b / GB:.2f} GB + cache {cache_b / GB:.2f} GB + one-row "
         f"cache {row_b / GB:.2f} GB = {need / GB:.2f} GB stays under 90% of "
         f"HBM ({share})")
     if limit:

@@ -98,6 +98,29 @@ def softmax_scale(cfg: ArchConfig) -> float:
     return cfg.attn_scale or cfg.head_dim ** -0.5
 
 
+LANES = 128     # the TPU's vector lanes
+
+
+def kv_width(cfg: ArchConfig) -> int:
+    """Width of one head's row in the K/V cache: the head dim rounded up to
+    a multiple of the 128 lanes.  The TPU lays a cache whose last axis is
+    so aligned out row-major, the layout the decode step's in-place row
+    write keeps.  At any other head dim (64, 80, 96, 120 and 224 compiled
+    for a v5e) it puts the sequence axis minor, and the step relayouts the
+    whole stack into and out of its scan every step, with two caches of
+    temporaries.  The zeros are bytes held and read: the K/V cache grows
+    by 6.7% at 120, 14.3% at 224 and 100% at 64, where the unpadded step's
+    temporaries would still peak higher.  q, k and v are padded with them,
+    which adds nothing to a score, and attention drops them from its
+    output."""
+    return -(-cfg.head_dim // LANES) * LANES
+
+
+def to_kv_width(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad the last (head) axis of x to `width`."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
 def gqa_forward(p: Params, x: jax.Array, positions: jax.Array, cfg: ArchConfig,
                 *, window: int = 0, causal: bool = True,
                 return_kv: bool = False):
@@ -121,10 +144,14 @@ def gqa_forward(p: Params, x: jax.Array, positions: jax.Array, cfg: ArchConfig,
     return out
 
 
-def gqa_decode(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
+def gqa_decode(p: Params, x: jax.Array, stack: dict, g, positions: jax.Array,
                cfg: ArchConfig, *, window: int = 0):
-    """One-token decode. x: (B,1,D); cache {k,v:(B,S,Hkv,hd)}; positions (B,)
-    or (B,3) absolute positions of the new token.  Returns (out, new_cache)."""
+    """One-token decode against layer g of a layer-stacked cache.
+
+    x: (B,1,D); stack {k,v: (G,B,S,Hkv,w)}, w = kv_width; positions (B,) or (B,3)
+    absolute positions of the new token.  Writes the token's K/V row into
+    layer g (at its ring slot when windowed) and attends over that layer.
+    Returns (out, the stack with the row written)."""
     b = x.shape[0]
     # positions for rope helpers expect (B,S) or (B,3,S)
     pos_seq = positions[:, None] if not cfg.use_mrope else positions[:, :, None]
@@ -135,7 +162,7 @@ def gqa_decode(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
     k = _rope(k, pos_seq, cfg)[:, 0]                               # (B,Hkv,hd)
     v = v[:, 0]
     tpos = _tpos(pos_seq, cfg)[:, 0]                               # (B,) int
-    cache_size = cache["k"].shape[1]
+    cache_size, width = stack["k"].shape[2], stack["k"].shape[-1]
     if window > 0:                      # ring-buffer cache for local layers
         size = min(window, cache_size)
         slot = tpos % size
@@ -144,12 +171,13 @@ def gqa_decode(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
         slot = tpos
         eff_len = tpos + 1
     bidx = jnp.arange(b)
-    k_cache = cache["k"].at[bidx, slot].set(k.astype(cache["k"].dtype))
-    v_cache = cache["v"].at[bidx, slot].set(v.astype(cache["v"].dtype))
-    o = ops.decode_attention(q, k_cache, v_cache, eff_len.astype(jnp.int32),
-                             scale=softmax_scale(cfg), use_kernel=cfg.use_kernels)
+    k_st = stack["k"].at[g, bidx, slot].set(to_kv_width(k, width).astype(stack["k"].dtype))
+    v_st = stack["v"].at[g, bidx, slot].set(to_kv_width(v, width).astype(stack["v"].dtype))
+    o = ops.decode_attention(to_kv_width(q, width), k_st[g], v_st[g],
+                             eff_len.astype(jnp.int32), scale=softmax_scale(cfg),
+                             use_kernel=cfg.use_kernels)[..., :cfg.head_dim]
     out = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None, :]
-    return out, {"k": k_cache, "v": v_cache}
+    return out, {"k": k_st, "v": v_st}
 
 
 def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
@@ -157,7 +185,7 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
     """Chunked prefill: C prompt tokens at once against the decode cache.
 
     x: (B,C,D); positions: (B,C) (or (B,3,C) M-RoPE) absolute, contiguous
-    ascending; cache {k,v:(B,S,Hkv,hd)}.  Writes the chunk's K/V rows into
+    ascending; cache {k,v:(B,S,Hkv,w)}, w = kv_width.  Writes the chunk's K/V rows into
     the cache and attends every query with the same grouped masked softmax
     the one-token decode path (`gqa_decode` -> decode_attention_ref) uses, so a
     P-token prompt costs O(P/C) calls instead of P decode steps while
@@ -170,11 +198,12 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    q = _rope(q, positions, cfg)                                   # (B,C,Hq,hd)
-    k = _rope(k, positions, cfg)                                   # (B,C,Hkv,hd)
+    cache_size, width = cache["k"].shape[1], cache["k"].shape[-1]
+    q = to_kv_width(_rope(q, positions, cfg), width)               # (B,C,Hq,w)
+    k = to_kv_width(_rope(k, positions, cfg), width)               # (B,C,Hkv,w)
     tpos = _tpos(positions, cfg)                                   # (B,C) int
-    cache_size = cache["k"].shape[1]
-    k_cd, v_cd = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
+    k_cd = k.astype(cache["k"].dtype)
+    v_cd = to_kv_width(v, width).astype(cache["v"].dtype)
 
     if window > 0:
         # Ring buffer of `size` slots.  At query position t the decode step
@@ -190,7 +219,7 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
         # position each slot held before the chunk (< 0: never written)
         held = (start - 1) - ((start - 1 - slots[None, :]) % size)   # (B,size)
         kpos = jnp.concatenate([held, tpos], axis=1)               # (B,size+C)
-        keys = jnp.concatenate([cache["k"], k_cd], axis=1)         # (B,size+C,Hkv,hd)
+        keys = jnp.concatenate([cache["k"], k_cd], axis=1)         # (B,size+C,Hkv,w)
         vals = jnp.concatenate([cache["v"], v_cd], axis=1)
         kq = kpos[:, None, :]
         tq = tpos[:, :, None]
@@ -211,7 +240,7 @@ def gqa_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
         v_cache = cache["v"].at[b2, tpos].set(v_cd)
         valid = jnp.arange(cache_size)[None, None, :] < (tpos[:, :, None] + 1)
         o = _grouped_attend(q, k_cache, v_cache, valid, softmax_scale(cfg))
-    out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    out = jnp.einsum("bshk,hkd->bsd", o[..., :cfg.head_dim], p["wo"])
     return out, {"k": k_cache, "v": v_cache}
 
 
@@ -232,76 +261,9 @@ def _grouped_attend(q: jax.Array, keys: jax.Array, vals: jax.Array,
     return o.reshape(b, c, hq, hd).astype(q.dtype)
 
 
-def gqa_decode_stacked(p: Params, x: jax.Array, stacked: dict, g: int,
-                       positions: jax.Array, cfg: ArchConfig, *, window: int = 0):
-    """One-token decode writing DIRECTLY into the layer-stacked cache
-    (G,B,S,Hkv,hd) via dynamic-update-slice -- no per-layer slice copy and
-    no post-scan restack (EXPERIMENTS.md §Perf C3: the functional per-layer
-    update cost two full cache copies per step)."""
-    b = x.shape[0]
-    pos_seq = positions[:, None] if not cfg.use_mrope else positions[:, :, None]
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    q = _rope(q, pos_seq, cfg)[:, 0]
-    k = _rope(k, pos_seq, cfg)[:, 0]
-    v = v[:, 0]
-    tpos = _tpos(pos_seq, cfg)[:, 0]
-    cache_size = stacked["k"].shape[2]
-    if window > 0:
-        size = min(window, cache_size)
-        slot = tpos % size
-        eff_len = jnp.minimum(tpos + 1, size)
-    else:
-        slot = tpos
-        eff_len = tpos + 1
-    bidx = jnp.arange(b)
-    k_st = stacked["k"].at[g, bidx, slot].set(k.astype(stacked["k"].dtype))
-    v_st = stacked["v"].at[g, bidx, slot].set(v.astype(stacked["v"].dtype))
-    o = ops.decode_attention(q, k_st[g], v_st[g], eff_len.astype(jnp.int32),
-                             scale=softmax_scale(cfg), use_kernel=cfg.use_kernels)
-    out = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None, :]
-    new = dict(stacked, k=k_st, v=v_st)
-    return out, new
-
-
-def mla_decode_stacked(p: Params, x: jax.Array, stacked: dict, g: int,
-                       positions: jax.Array, cfg: ArchConfig):
-    """Absorbed-form MLA decode over the stacked compressed cache (§Perf C3)."""
-    b = x.shape[0]
-    r = cfg.kv_lora_rank
-    pos_seq = positions[:, None]
-    c = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"])
-    c_new, krope_new = c[..., :r][:, 0], c[..., r:]
-    krope_new = apply_rope(krope_new[:, :, None, :], pos_seq, cfg.rope_theta)[:, 0, 0]
-    bidx = jnp.arange(b)
-    ckv_st = stacked["c_kv"].at[g, bidx, positions].set(
-        c_new.astype(stacked["c_kv"].dtype))
-    krope_st = stacked["k_rope"].at[g, bidx, positions].set(
-        krope_new.astype(stacked["k_rope"].dtype))
-    c_kv, k_rope = ckv_st[g], krope_st[g]
-
-    q_nope = jnp.einsum("bsd,dhk->bshk", x, p["w_uq"])[:, 0]
-    q_rope = apply_rope(jnp.einsum("bsd,dhk->bshk", x, p["w_qr"]), pos_seq,
-                        cfg.rope_theta)[:, 0]
-    q_abs = jnp.einsum("bhk,rhk->bhr", q_nope, p["w_uk"])
-    logits = (jnp.einsum("bhr,bsr->bhs", q_abs.astype(c_kv.dtype), c_kv,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bhk,bsk->bhs", q_rope.astype(k_rope.dtype), k_rope,
-                           preferred_element_type=jnp.float32)) * _mla_scale(cfg)
-    valid = jnp.arange(c_kv.shape[1])[None, :] <= positions[:, None]
-    logits = jnp.where(valid[:, None, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    o_c = jnp.einsum("bhs,bsr->bhr", probs.astype(c_kv.dtype), c_kv,
-                     preferred_element_type=jnp.float32)
-    o = jnp.einsum("bhr,rhk->bhk", o_c.astype(x.dtype), p["w_uv"])
-    out = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None, :]
-    return out, dict(stacked, c_kv=ckv_st, k_rope=krope_st)
-
-
 def gqa_cache_shape(cfg: ArchConfig, batch: int, length: int, window: int = 0):
     size = min(window, length) if window > 0 else length
-    kv = (batch, size, cfg.n_kv_heads, cfg.head_dim)
+    kv = (batch, size, cfg.n_kv_heads, kv_width(cfg))
     return {"k": kv, "v": kv}
 
 
@@ -390,11 +352,13 @@ def mla_forward(p: Params, x: jax.Array, positions: jax.Array, cfg: ArchConfig,
     return out
 
 
-def mla_decode(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
+def mla_decode(p: Params, x: jax.Array, stack: dict, g, positions: jax.Array,
                cfg: ArchConfig):
-    """Absorbed-form decode over the compressed cache.
+    """Absorbed-form decode against layer g of the stacked compressed cache.
 
-    cache: {c_kv: (B,S,r), k_rope: (B,S,dr)}; positions: (B,) absolute."""
+    stack: {c_kv: (G,B,S,r), k_rope: (G,B,S,dr)}; positions: (B,) absolute.
+    Writes the token's c_kv / k_rope rows into layer g and attends over that
+    layer.  Returns (out, the stack with the rows written)."""
     b = x.shape[0]
     r = cfg.kv_lora_rank
     pos_seq = positions[:, None]
@@ -402,8 +366,11 @@ def mla_decode(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
     c_new, krope_new = c[..., :r][:, 0], c[..., r:]
     krope_new = apply_rope(krope_new[:, :, None, :], pos_seq, cfg.rope_theta)[:, 0, 0]
     bidx = jnp.arange(b)
-    c_kv = cache["c_kv"].at[bidx, positions].set(c_new.astype(cache["c_kv"].dtype))
-    k_rope = cache["k_rope"].at[bidx, positions].set(krope_new.astype(cache["k_rope"].dtype))
+    ckv_st = stack["c_kv"].at[g, bidx, positions].set(
+        c_new.astype(stack["c_kv"].dtype))
+    krope_st = stack["k_rope"].at[g, bidx, positions].set(
+        krope_new.astype(stack["k_rope"].dtype))
+    c_kv, k_rope = ckv_st[g], krope_st[g]
 
     q_nope = jnp.einsum("bsd,dhk->bshk", x, p["w_uq"])[:, 0]       # (B,H,dn)
     q_rope = apply_rope(jnp.einsum("bsd,dhk->bshk", x, p["w_qr"]), pos_seq,
@@ -423,7 +390,7 @@ def mla_decode(p: Params, x: jax.Array, cache: dict, positions: jax.Array,
                      preferred_element_type=jnp.float32)               # (B,H,r)
     o = jnp.einsum("bhr,rhk->bhk", o_c.astype(x.dtype), p["w_uv"])     # (B,H,dv)
     out = jnp.einsum("bhk,hkd->bd", o, p["wo"])[:, None, :]
-    return out, {"c_kv": c_kv, "k_rope": k_rope}
+    return out, {"c_kv": ckv_st, "k_rope": krope_st}
 
 
 def mla_prefill(p: Params, x: jax.Array, cache: dict, positions: jax.Array,

@@ -222,15 +222,22 @@ def _mixer_forward(p, x, positions, cfg, kind, collect_cache: bool):
     return fwd(p, x, cfg), None
 
 
-def _mixer_decode(p, x, cache, positions, cfg, kind):
+def _mixer_decode(p, x, stack, g, positions, cfg, kind):
+    """The mixer's one-token step against layer g of its slot's stacked
+    cache.  Returns (out, the leaves it wrote, as whole stacks): an
+    attention kind writes the new token's row, a recurrent kind rewrites
+    its layer's state."""
     if kind in ("global", "local"):
         window = cfg.sliding_window if kind == "local" else 0
-        return attn.gqa_decode(p, x, cache, positions, cfg, window=window)
+        return attn.gqa_decode(p, x, stack, g, positions, cfg, window=window)
     if kind == "mla":
-        return attn.mla_decode(p, x, cache, positions, cfg)
+        return attn.mla_decode(p, x, stack, g, positions, cfg)
     dec = {"mamba2": ssm_mod.mamba2_decode, "mlstm": ssm_mod.mlstm_decode,
            "slstm": ssm_mod.slstm_decode}[kind]
-    return dec(p, x, cache, cfg)
+    state = {k: a[g] for k, a in stack.items()}
+    out, new = dec(p, x, state, cfg)
+    return out, {k: stack[k].at[g].set(v.astype(stack[k].dtype))
+                 for k, v in new.items()}
 
 
 def _norm_in(p: Params, x: jax.Array, cfg: ArchConfig, shared):
@@ -266,22 +273,25 @@ def mixer_scope(kind: str) -> str:
     return "attention" if kind in ("global", "local", "mla") else kind
 
 
-def slot_decode(p: Params, x: jax.Array, cache, positions, cfg: ArchConfig,
-                kind: str, ffn: str, *, enc_kv=None, shared=None):
+def slot_decode(p: Params, x: jax.Array, stack, g, positions, cfg: ArchConfig,
+                kind: str, ffn: str, *, shared=None):
+    """One token through layer g of a phase: `stack` is the slot's cache
+    stacked over the phase's groups.  Returns (x, the stack with what this
+    step changed written at layer g); audio cross K/V is only read."""
     xn = _norm_in(p, x, cfg, shared)
     with jax.named_scope(mixer_scope(kind)):
-        mix_out, new_cache = _mixer_decode(p["mixer"], xn, cache, positions, cfg, kind)
+        mix_out, written = _mixer_decode(p["mixer"], xn, stack, g, positions, cfg, kind)
     x = x + mix_out
-    if enc_kv is not None:
+    if "cross_k" in stack:
         x = x + attn.cross_decode(p["cross"], nn.rms_norm(x, p["norm_x"], cfg.norm_eps),
-                                  enc_kv, cfg)
+                                  (stack["cross_k"][g], stack["cross_v"][g]), cfg)
     if ffn != "none":
         h = nn.rms_norm(x, p["norm2"], cfg.norm_eps)
         with jax.named_scope("ffn"):
             y = (moe_mod.moe_forward(p["ffn"], h, cfg)[0] if ffn == "moe"
                  else mlp_forward(p["ffn"], h, cfg))
         x = x + y
-    return x, new_cache
+    return x, dict(stack, **written)
 
 
 def _mixer_prefill(p, x, cache, positions, cfg, kind):
@@ -313,39 +323,6 @@ def slot_prefill(p: Params, x: jax.Array, cache, positions, cfg: ArchConfig,
                  if ffn == "moe" else mlp_forward(p["ffn"], h, cfg))
         x = x + y
     return x, new_cache
-
-
-def slot_decode_stacked(p: Params, x: jax.Array, stacked, g: int, positions,
-                        cfg: ArchConfig, kind: str, ffn: str, *, enc_kv=None):
-    """slot_decode against the layer-STACKED cache: attention kinds update
-    in place via dynamic-update-slice (§Perf C3); recurrent kinds read the
-    layer slice and write the (small) state back at group index g."""
-    xn = nn.rms_norm(x, p["norm1"], cfg.norm_eps)
-    if kind in ("global", "local"):
-        window = cfg.sliding_window if kind == "local" else 0
-        mix_out, stacked = attn.gqa_decode_stacked(p["mixer"], xn, stacked, g,
-                                                   positions, cfg, window=window)
-    elif kind == "mla":
-        mix_out, stacked = attn.mla_decode_stacked(p["mixer"], xn, stacked, g,
-                                                   positions, cfg)
-    else:
-        dec = {"mamba2": ssm_mod.mamba2_decode, "mlstm": ssm_mod.mlstm_decode,
-               "slstm": ssm_mod.slstm_decode}[kind]
-        state_keys = slot_cache_shape(cfg, kind, 1, 1).keys()
-        layer_state = {k: stacked[k][g] for k in state_keys}
-        mix_out, new_state = dec(p["mixer"], xn, layer_state, cfg)
-        stacked = dict(stacked, **{k: stacked[k].at[g].set(
-            new_state[k].astype(stacked[k].dtype)) for k in state_keys})
-    x = x + mix_out
-    if enc_kv is not None:
-        x = x + attn.cross_decode(p["cross"], nn.rms_norm(x, p["norm_x"], cfg.norm_eps),
-                                  enc_kv, cfg)
-    if ffn != "none":
-        h = nn.rms_norm(x, p["norm2"], cfg.norm_eps)
-        y = (moe_mod.moe_forward(p["ffn"], h, cfg)[0] if ffn == "moe"
-             else mlp_forward(p["ffn"], h, cfg))
-        x = x + y
-    return x, stacked
 
 
 def slot_cache_shape(cfg: ArchConfig, kind: str, batch: int, length: int):

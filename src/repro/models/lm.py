@@ -123,9 +123,10 @@ def _shared_params(params, cfg: ArchConfig, phase, g):
 
 
 def _cached_window(cfg: ArchConfig, kv: dict) -> int:
-    """Window of a shared block's cache: windowed iff it was built as a ring
-    (size at most the window, see _shared_window)."""
-    return cfg.sliding_window if kv["k"].shape[1] <= cfg.sliding_window else 0
+    """Window of a shared block's cache, one layer's (B,S,H,hd) or stacked
+    (G,B,S,H,hd): windowed iff it was built as a ring (size at most the
+    window, see _shared_window)."""
+    return cfg.sliding_window if kv["k"].shape[-3] <= cfg.sliding_window else 0
 
 
 def _positions_for(cfg: ArchConfig, batch) -> jax.Array:
@@ -222,9 +223,12 @@ def _shared_window(cfg: ArchConfig, cache_len: int) -> int:
 
 
 def _pad_cache(c: dict, kind: str, cfg: ArchConfig, cache_len: int, window: int = 0):
-    """Pad prefill-emitted kv to the decode cache length (ring-aware)."""
+    """Pad prefill-emitted kv to the decode cache length (ring-aware), and
+    GQA's k/v to the cache's row width (attention.kv_width)."""
     if kind not in ("global", "local", "mla") or not cache_len:
         return c
+    if kind != "mla":
+        c = {n: attn.to_kv_width(a, attn.kv_width(cfg)) for n, a in c.items()}
     if kind == "local" or window:
         w = window or cfg.sliding_window
         size = min(w, cache_len)
@@ -259,12 +263,14 @@ def decode_step(params, cfg: ArchConfig, tokens, positions, cache):
     """tokens: (B,1); positions: (B,) (or (B,3) M-RoPE) absolute position of
     the new token.  Returns (logits (B,V), new_cache).
 
-    Formulation note (EXPERIMENTS.md §Perf C3, refuted): an in-place
-    variant updating the layer-STACKED cache via chained scatters
-    (blocks.slot_decode_stacked) measured 5x WORSE -- XLA lowers each
-    full-stack scatter as a whole-buffer copy.  The scan-with-ys form
-    below (slice scatter + ys restack, ~2 cache copies/step) is the
-    better-measured baseline and is kept."""
+    Each phase's stacked cache rides in its layer scan's carry; only the
+    params and the group index are scanned.  Group g writes what its step
+    changes straight into the stack -- an attention layer the new token's
+    row (its ring slot if windowed, the c_kv/k_rope rows for MLA), a
+    recurrent layer its whole state -- and attention reads layer g of the
+    stack.  No layer is sliced out and stacked again, so where the caller
+    donates the cache (serving/continuous.py) the step writes one row per
+    attention layer in place."""
     params = nn.cast_tree(params, cfg.compute_dtype)   # mixed precision
     plan = blocks.build_plan(cfg)
     x = emb = _embed(params, cfg, tokens)
@@ -274,35 +280,27 @@ def decode_step(params, cfg: ArchConfig, tokens, positions, cache):
     new_cache: dict = {}
 
     for pi, phase in enumerate(plan):
-        stacked = params[f"phase{pi}"]
-        pcache = cache[f"phase{pi}"]
-
-        def group_fn(h, xs, phase=phase):
-            gp, gc, g = xs
-            out_c = {}
+        def group_fn(carry, xs, phase=phase):
+            h, pc = carry
+            gp, g = xs
+            pc = dict(pc)
             shared = None
             if phase.hybrid:
-                w = _cached_window(cfg, gc["shared"])
-                shared, out_c["shared"] = blocks.shared_block(
+                w = _cached_window(cfg, pc["shared"])
+                shared, pc["shared"] = blocks.shared_block(
                     _shared_params(params, cfg, phase, g), gp["hybrid"], h, emb, cfg,
-                    lambda mp, s: attn.gqa_decode(mp, s, gc["shared"], positions,
+                    lambda mp, s: attn.gqa_decode(mp, s, pc["shared"], g, positions,
                                                   cfg, window=w))
             for j, (kind, ffn) in enumerate(zip(phase.kinds, phase.ffns)):
-                sc = dict(gc[f"slot{j}"])
-                enc_kv = None
-                if cfg.family == "audio":
-                    enc_kv = (sc.pop("cross_k"), sc.pop("cross_v"))
-                h, nc = blocks.slot_decode(gp[f"slot{j}"], h, sc, positions, cfg,
-                                           kind, ffn, enc_kv=enc_kv,
-                                           shared=shared if j == 0 else None)
-                if enc_kv is not None:
-                    nc = dict(nc, cross_k=enc_kv[0], cross_v=enc_kv[1])
-                out_c[f"slot{j}"] = nc
-            return h, out_c
+                h, pc[f"slot{j}"] = blocks.slot_decode(
+                    gp[f"slot{j}"], h, pc[f"slot{j}"], g, positions, cfg, kind, ffn,
+                    shared=shared if j == 0 else None)
+            return (h, pc), None
 
-        x, pc = jax.lax.scan(group_fn, x, (stacked, pcache, _group_ids(phase)),
-                             unroll=True if cfg.scan_unroll else 1)
-        new_cache[f"phase{pi}"] = pc
+        (x, new_cache[f"phase{pi}"]), _ = jax.lax.scan(
+            group_fn, (x, cache[f"phase{pi}"]),
+            (params[f"phase{pi}"], jnp.arange(phase.n_groups)),
+            unroll=True if cfg.scan_unroll else 1)
 
     if cfg.family == "audio":
         new_cache["enc_len"] = cache["enc_len"]

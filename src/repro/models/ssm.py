@@ -126,22 +126,22 @@ def mamba2_forward(p: Params, x: jax.Array, cfg: ArchConfig,
 
 def mamba2_decode(p: Params, x: jax.Array, cache: dict, cfg: ArchConfig):
     """x: (B,1,D); cache {ssm:(B,H,P,N) f32, conv:(B,k-1,convdim) f32}.
-    Head h reads B/C group h // (H // G)."""
-    b, g = x.shape[0], cfg.ssm_groups
+    Head h reads B/C group h // (H // G).  B and C are repeated to the
+    heads, so the state is updated in its own (B,H,P,N) shape: split into
+    groups, the TPU would retile every layer's state for its update."""
     z, xbc_new, dt = _mamba2_in(p, x)
     window = jnp.concatenate([cache["conv"], xbc_new], axis=1)
     xin, Bm, Cm = _mamba2_split(_conv_silu(p, window, 1)[:, 0], cfg)  # (B,H,P),(B,G,N)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
     dt = dt[:, 0]                                                  # (B,H)
-    hstate = cache["ssm"].reshape(b, g, cfg.ssm_heads // g, cfg.ssm_head_dim, -1)
-    xg = xin.reshape(hstate.shape[:-1])
-    dtg = dt.reshape(b, g, -1)
-    decay = jnp.exp(A.reshape(g, -1)[None] * dtg)                  # (B,G,Hg)
-    inject = jnp.einsum("bgh,bghp,bgn->bghpn", dtg, xg, Bm)
-    hstate = hstate * decay[..., None, None] + inject
-    y = jnp.einsum("bghpn,bgn->bghp", hstate, Cm)
-    out = _mamba2_out(p, y.reshape(xin.shape), xin, z[:, 0], cfg)[:, None, :]
-    return out, {"ssm": hstate.reshape(cache["ssm"].shape), "conv": window[:, 1:, :]}
+    rep = cfg.ssm_heads // cfg.ssm_groups                          # heads per group
+    Bh, Ch = (jnp.repeat(m, rep, axis=1) for m in (Bm, Cm))        # (B,H,N)
+    decay = jnp.exp(A[None] * dt)                                  # (B,H)
+    inject = jnp.einsum("bh,bhp,bhn->bhpn", dt, xin, Bh)
+    hstate = cache["ssm"] * decay[..., None, None] + inject
+    y = jnp.einsum("bhpn,bhn->bhp", hstate, Ch)
+    out = _mamba2_out(p, y, xin, z[:, 0], cfg)[:, None, :]
+    return out, {"ssm": hstate, "conv": window[:, 1:, :]}
 
 
 def mamba2_prefill(p: Params, x: jax.Array, cache: dict, cfg: ArchConfig):

@@ -60,6 +60,25 @@ def _emit(req: Request, token: int):
         req.t_first = time.perf_counter()
 
 
+def _on_slot_rows(cache, max_slots: int, f, *rows):
+    """f(leaf, *row leaves) on each phase-cache leaf that holds a row per
+    slot (axis 1 of size max_slots); every other leaf as it is."""
+    def each(a, *r):
+        return f(a, *r) if a.ndim >= 2 and a.shape[1] == max_slots else a
+    return {k: (jax.tree_util.tree_map(each, v, *(r[k] for r in rows))
+                if k.startswith("phase") else v)
+            for k, v in cache.items()}
+
+
+def decode_program(cfg: ArchConfig):
+    """lm.decode_step as the batcher serves it: jitted with the cache
+    donated, so that its rows are written in place."""
+    def serve_decode(p, c, t, pos):
+        return lm.decode_step(p, cfg, t, pos, c)
+
+    return jax.jit(serve_decode, donate_argnums=1)
+
+
 @dataclasses.dataclass
 class _Slot:
     req: Optional[Request] = None
@@ -87,13 +106,22 @@ class ContinuousBatcher:
         self.step_count = 0
         self._next_rid = 0
 
-        def serve_decode(p, c, t, pos):
-            return lm.decode_step(p, cfg, t, pos, c)
-
         def serve_prefill(p, c, t, pos):
             return lm.prefill_chunk(p, cfg, t, pos, c)
 
-        self._decode = jax.jit(serve_decode)
+        self._decode = decode_program(cfg)
+
+        def zero_row(c, i):
+            return _on_slot_rows(c, max_slots, lambda a: a.at[:, i].set(0))
+
+        def put_row(c, row, i):
+            return _on_slot_rows(
+                c, max_slots, lambda a, r: a.at[:, i].set(r[:, 0].astype(a.dtype)),
+                row)
+
+        # admission writes one slot's row in place, as the decode step does
+        self._zero_row = jax.jit(zero_row, donate_argnums=0)
+        self._put_row = jax.jit(put_row, donate_argnums=0)
         if self.prefill_chunk > 0:
             self._prefill = jax.jit(serve_prefill)
             self._row_cache_zeros = lm.init_cache(cfg, 1, max_len)
@@ -127,16 +155,8 @@ class ContinuousBatcher:
         """Zero cache row i: KV rows would be masked by eff_len anyway, but
         recurrent state (SSM/mLSTM carries) PERSISTS across occupants and
         must be cleared at re-admission."""
-        def zero_row(a):
-            if a.ndim >= 2 and a.shape[1] == self.max_slots:
-                return a.at[:, i].set(jnp.zeros_like(a[:, i]))
-            return a
         with TraceAnnotation("serve.reset_row"):
-            self.cache = {
-                k: (jax.tree_util.tree_map(zero_row, v) if k.startswith("phase")
-                    else v)
-                for k, v in self.cache.items()
-            }
+            self.cache = self._zero_row(self.cache, i)
 
     def _admit(self):
         for i, s in enumerate(self.slots):
@@ -206,16 +226,8 @@ class ContinuousBatcher:
 
     def _scatter_row(self, i: int, row_cache):
         """Copy a one-row prefill cache into row i of the shared cache."""
-        def put(dst, src):
-            if dst.ndim >= 2 and dst.shape[1] == self.max_slots:
-                return dst.at[:, i].set(src[:, 0].astype(dst.dtype))
-            return dst
         with TraceAnnotation("serve.scatter_row"):
-            self.cache = {
-                k: (jax.tree_util.tree_map(put, v, row_cache[k])
-                    if k.startswith("phase") else v)
-                for k, v in self.cache.items()
-            }
+            self.cache = self._put_row(self.cache, row_cache, i)
 
     # -- engine -------------------------------------------------------------
     def step(self):

@@ -164,3 +164,95 @@ def test_requests_carry_their_clock_times(setup, prefill_chunk):
         assert r.t_submit <= r.t_admit <= r.t_first <= r.t_done
     waits = [r.t_admit - r.t_submit for r in reqs]
     assert waits[-1] == max(waits)
+
+
+def _scan_ys_decode_step(params, cfg, tokens, positions, cache):
+    """Reference: the decode step before its cache rode in the layer scan's
+    carry.  Each phase's cache is scanned as xs and restacked as ys, one
+    layer at a time, and nothing is donated; the layer code is handed each
+    layer's cache as a stack of one and writes it at index 0."""
+    from repro.models import attention as attn
+    from repro.models import blocks
+    from repro.models import modules as nn
+    params = nn.cast_tree(params, cfg.compute_dtype)
+    x = emb = lm._embed(params, cfg, tokens)
+    new_cache = {}
+    for pi, phase in enumerate(blocks.build_plan(cfg)):
+        def group_fn(h, xs, phase=phase):
+            gp, gc, g = xs
+            one = jax.tree_util.tree_map(lambda a: a[None], gc)
+            out, shared = {}, None
+            if phase.hybrid:
+                w = lm._cached_window(cfg, gc["shared"])
+                shared, out["shared"] = blocks.shared_block(
+                    lm._shared_params(params, cfg, phase, g), gp["hybrid"], h, emb,
+                    cfg, lambda mp, s: attn.gqa_decode(mp, s, one["shared"], 0,
+                                                       positions, cfg, window=w))
+            for j, (kind, ffn) in enumerate(zip(phase.kinds, phase.ffns)):
+                h, out[f"slot{j}"] = blocks.slot_decode(
+                    gp[f"slot{j}"], h, one[f"slot{j}"], 0, positions, cfg, kind,
+                    ffn, shared=shared if j == 0 else None)
+            return h, jax.tree_util.tree_map(lambda a: a[0], out)
+
+        x, new_cache[f"phase{pi}"] = jax.lax.scan(
+            group_fn, x, (params[f"phase{pi}"], cache[f"phase{pi}"],
+                          jnp.arange(phase.n_groups)))
+    return lm._head(params, cfg, x[:, 0]), new_cache
+
+
+# one smoke config per kind of cache, with two groups in a phase where the
+# smoke config has one, so that the step writes at a layer index past 0
+CACHE_KINDS = {
+    "h2o_danube_3_4b": dict(block_pattern=("global",), sliding_window=0),
+    "gemma3_4b": dict(n_layers=4),                  # ring (window 16) + global
+    "deepseek_v2_lite_16b": dict(n_layers=3),       # MLA after a dense layer
+    "zamba2_7b": dict(n_layers=6, hybrid_layer_ids=(1, 3, 5)),  # Mamba2 + shared K/V
+    "xlstm_1_3b": dict(n_layers=4),                 # mLSTM / sLSTM state
+}
+
+
+def _serve(cb, prompts):
+    """Two requests, three steps, then two more admitted mid-run into slots
+    the first ones free.  Returns (outputs, logits of every decode step,
+    whether every step's cache was donated)."""
+    logits, donated = [], []
+    decode = cb._decode
+
+    def recording(p, c, t, pos):
+        out = decode(p, c, t, pos)
+        logits.append(np.asarray(out[0], np.float32))
+        donated.append(all(a.is_deleted() for a in jax.tree_util.tree_leaves(c)))
+        return out
+
+    cb._decode = recording
+    reqs = [cb.submit(p, max_new=n) for p, n in prompts[:2]]
+    for _ in range(3):
+        cb.step()
+    reqs += [cb.submit(p, max_new=n) for p, n in prompts[2:]]
+    cb.run()
+    return [r.output for r in reqs], np.stack(logits), all(donated)
+
+
+@pytest.mark.parametrize("arch", sorted(CACHE_KINDS))
+def test_donated_decode_matches_scan_ys_reference(arch):
+    """The batcher's decode step, with the cache carried, donated and held
+    in one layout, serves the tokens and logits of the undonated scan-ys
+    form, through admissions that reset and scatter rows of a donated
+    cache, past the ring window."""
+    cfg = registry.get_smoke_config(arch).replace(**CACHE_KINDS[arch])
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    prompts = [(rng.integers(1, cfg.vocab_size, n).tolist(), m)
+               for n, m in ((8, 3), (16, 12), (24, 9), (8, 6))]
+
+    cb = ContinuousBatcher(cfg, params, max_slots=2, max_len=40, prefill_chunk=8)
+    got, got_logits, donated = _serve(cb, prompts)
+    assert donated, "a decode step kept the cache it was given"
+
+    ref = ContinuousBatcher(cfg, params, max_slots=2, max_len=40, prefill_chunk=8)
+    ref._decode = jax.jit(
+        lambda p, c, t, pos: _scan_ys_decode_step(p, cfg, t, pos, c))
+    want, want_logits, _ = _serve(ref, prompts)
+
+    assert got == want
+    np.testing.assert_allclose(got_logits, want_logits, rtol=2e-3, atol=2e-3)
