@@ -18,12 +18,7 @@ analyzer's per-stage latency-breakdown table is derived from the spans;
 and an instrumentation-overhead race (the same stream run bare and fully
 instrumented on one seed) that must keep the traced hot-loop wall within
 10% of untraced while leaving the simulation outcome bit-identical --
-plus a DISAGG scenario (ISSUE 8): a real ContinuousBatcher raced with
-chunked batched prefill against the teacher-forced seed path on a
-prefill-heavy mix, gated by an output-identity oracle leg; the
-disaggregated path must clear the asserted token-throughput floor (2x
-full, 1.3x smoke) without regressing the decode-step p99 -- plus a
-DRIFT scenario (ISSUE 10): the profiling DAG measures the backend into
+plus a DRIFT scenario (ISSUE 10): the profiling DAG measures the backend into
 ModelProfile artifacts per cloud, a profile-planned placement (every
 demand number from the store, zero hand-tuned constants) races the
 hand-tuned plan within 1.1x on p99, then an injected service-time shift
@@ -165,25 +160,6 @@ def validate_bench(bench: dict, require: tuple = ()) -> None:
         # the full tier must really be the >=10^6-request scenario
         if s5["asserted_min_speedup"] >= 50 and s5["requests"] < 10 ** 6:
             raise ValueError(f"scale tier ran only {s5['requests']} requests")
-    if "disagg" in sc:
-        dg = sc["disagg"]
-        require_keys(dg, ("oracle_ok", "requests", "prompt_tokens",
-                          "gen_tokens", "chunk", "seed", "disagg", "speedup",
-                          "asserted_min_speedup"), "disagg scenario")
-        if not dg["oracle_ok"]:
-            raise ValueError("disagg race ran without a passing oracle leg")
-        for side in ("seed", "disagg"):
-            require_keys(dg[side], ("wall_s", "tokens_per_s",
-                                    "decode_step_p99_s", "steps"),
-                         f"disagg.{side}")
-        if dg["speedup"] < dg["asserted_min_speedup"]:
-            raise ValueError(
-                f"disagg token-throughput speedup {dg['speedup']}x below "
-                f"the asserted {dg['asserted_min_speedup']}x floor")
-        if dg["disagg"]["decode_step_p99_s"] > \
-                1.3 * dg["seed"]["decode_step_p99_s"]:
-            raise ValueError("disagg decode-step p99 regressed past the "
-                             "1.3x noise guard")
     if "observability" in sc:
         ob = sc["observability"]
         require_keys(ob, ("wall_untraced_s", "wall_traced_s",
@@ -337,10 +313,9 @@ def run() -> list[dict]:
     rows.extend(_drift_scenario(preds["small"], bench))
     rows.extend(_contention_scenario(preds["small"], bench))
     rows.extend(_scale_scenario(bench))
-    rows.extend(_disagg_scenario(bench))
     validate_bench(bench, require=("fleet", "slo_failover", "split_cost",
                                    "overload", "observability", "drift",
-                                   "contention", "scale", "disagg"))
+                                   "contention", "scale"))
     BENCH_JSON.write_text(json.dumps(bench, indent=1, sort_keys=True))
     print(f"wrote {BENCH_JSON}", file=sys.stderr)
     return rows
@@ -1236,152 +1211,18 @@ def _scale_scenario(bench: dict, *, smoke: bool = False) -> list[dict]:
     }]
 
 
-# -- disagg tier (ISSUE 8): chunked prefill vs teacher-forced decode --------
-
-def _disagg_scenario(bench: dict, *, smoke: bool = False) -> list[dict]:
-    """ISSUE 8 acceptance: the SAME prefill-heavy request mix drained
-    through a real ContinuousBatcher two ways on one host -- the
-    teacher-forced seed path (prefill_chunk=0: a P-token prompt costs P
-    full decode steps across the whole slot pool) and the disaggregated
-    path (prefill_chunk=C: the prompt runs through the batched
-    flash-attention prefill in ceil(P/C) calls and enters the decode pool
-    with its first token emitted).
-
-    The ORACLE leg runs first: both paths must emit identical output
-    tokens for every request (the bit-level logits oracle lives in
-    tests/test_prefill_oracle.py; this pins the bench's own mix) -- a
-    throughput number from a diverged model is meaningless.  The timed
-    race then drives step() manually: steps in which admission ran a
-    prompt prefill are excluded from the DECODE-step latency sample (they
-    are prefill cost, already paid inside the wall), so the p99 guard
-    compares pure decode steps against pure decode steps.  Acceptance:
-    >=2x token throughput (>=1.3x on the reduced smoke cut) and a
-    decode-step p99 within the 1.3x noise guard of the seed path."""
-    from repro.configs import registry
-    from repro.models import lm
-    from repro.serving.continuous import ContinuousBatcher
-
-    P, G = (48, 4) if smoke else (96, 4)
-    n_req = 6 if smoke else 8
-    chunk = 8 if smoke else 32       # P % chunk == 0: one prefill shape
-    slots = 2 if smoke else 4
-    min_speedup = 1.3 if smoke else 2.0
-    arch = "h2o_danube_3_4b"
-    cfg = registry.get_smoke_config(arch)
-    params = lm.init_params(jax.random.PRNGKey(0), cfg)
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(1, cfg.vocab_size, P).tolist()
-               for _ in range(n_req)]
-
-    def make(pc: int) -> ContinuousBatcher:
-        return ContinuousBatcher(cfg, params, max_slots=slots,
-                                 max_len=P + G + 4, prefill_chunk=pc)
-
-    # oracle leg: identical outputs before any timing means anything
-    outs = {}
-    for pc in (0, chunk):
-        b = make(pc)
-        reqs = [b.submit(list(p), G) for p in prompts]
-        b.run()
-        outs[pc] = [r.output for r in reqs]
-    oracle_ok = outs[0] == outs[chunk]
-    assert oracle_ok, "disagg race oracle: outputs diverged from seed"
-
-    def timed_once(pc: int) -> dict:
-        b = make(pc)
-        b.submit(list(prompts[0]), G)
-        b.run()                          # warmup: compile both phase shapes
-        for p in prompts:
-            b.submit(list(p), G)
-        decode_walls, admit_steps = [], 0
-        t0 = time.perf_counter()
-        while b.queue or b.active:
-            pf0 = b.prefill_stats["requests"] if pc else 0
-            s0 = time.perf_counter()
-            b.step()
-            w = time.perf_counter() - s0
-            if pc and b.prefill_stats["requests"] > pf0:
-                admit_steps += 1         # prompt ingest ran inside this step
-            else:
-                decode_walls.append(w)
-        wall = time.perf_counter() - t0
-        toks = n_req * (P + G)
-        return {"wall_s": wall, "tokens_per_s": toks / wall,
-                "decode_step_p99_s": float(np.percentile(decode_walls, 99)),
-                "steps": len(decode_walls) + admit_steps,
-                "prefill_steps": admit_steps}
-
-    def timed(pc: int) -> dict:
-        # min-of-reps, like the observability race: back-to-back reps share
-        # the box's thermal/GC state, so min wall is the noise-robust
-        # estimator for a fixed amount of work
-        return min((timed_once(pc) for _ in range(3)),
-                   key=lambda s: s["wall_s"])
-
-    seed_side = timed(0)
-    dis_side = timed(chunk)
-    speedup = dis_side["tokens_per_s"] / seed_side["tokens_per_s"]
-
-    print(f"disagg race ({arch} smoke config, {n_req} reqs x "
-          f"P={P} G={G}, chunk={chunk}, slots={slots}):", file=sys.stderr)
-    for tag, s in (("seed", seed_side), ("disagg", dis_side)):
-        print(f"  {tag:<8}{s['tokens_per_s']:>10.0f} tok/s  "
-              f"wall {s['wall_s'] * 1e3:8.1f}ms  steps {s['steps']:>4}  "
-              f"decode_p99 {s['decode_step_p99_s'] * 1e3:.2f}ms",
-              file=sys.stderr)
-    print(f"  -> {speedup:.2f}x token throughput", file=sys.stderr)
-
-    # acceptance: throughput floor + decode-tail non-regression
-    assert speedup >= min_speedup, \
-        f"disagg speedup {speedup:.2f}x < {min_speedup}x"
-    assert dis_side["decode_step_p99_s"] <= \
-        1.3 * seed_side["decode_step_p99_s"], \
-        (dis_side["decode_step_p99_s"], seed_side["decode_step_p99_s"])
-
-    def side(s):
-        return {"wall_s": round(s["wall_s"], 6),
-                "tokens_per_s": round(s["tokens_per_s"], 1),
-                "decode_step_p99_s": round(s["decode_step_p99_s"], 6),
-                "steps": s["steps"],
-                "prefill_steps": s["prefill_steps"]}
-
-    bench["scenarios"]["disagg"] = {
-        "oracle_ok": oracle_ok,
-        "arch": arch,
-        "requests": n_req,
-        "prompt_tokens": P,
-        "gen_tokens": G,
-        "chunk": chunk,
-        "slots": slots,
-        "seed": side(seed_side),
-        "disagg": side(dis_side),
-        "speedup": round(speedup, 2),
-        "asserted_min_speedup": min_speedup}
-    return [{
-        "name": "gateway_disagg_race",
-        "us_per_call": 1e6 / dis_side["tokens_per_s"],
-        "derived": f"speedup={speedup:.2f}x;"
-                   f"disagg_tok_s={dis_side['tokens_per_s']:.0f};"
-                   f"seed_tok_s={seed_side['tokens_per_s']:.0f};"
-                   f"decode_p99_ms={dis_side['decode_step_p99_s'] * 1e3:.3f};"
-                   f"P={P};G={G};chunk={chunk}",
-    }]
-
-
 def smoke() -> None:
     """CI bench-smoke: run the overload scenario (with its burn-rate
     telemetry leg), the instrumentation-overhead race, the contention
     race (ISSUE 9: training + serving burst through one CapacityMarket,
     priority on vs off), the model-CI drift tier (ISSUE 10:
     profile-planned placement + injected shift -> profile:drift before
-    the profile_drift migrate), the reduced scale tier (engine oracle +
-    >=10x vector-over-scalar on a smaller request count) and the reduced
-    disagg tier (output oracle + >=1.3x chunked-prefill token
-    throughput), then validate both the freshly produced record and
+    the profile_drift migrate) and the reduced scale tier (engine oracle +
+    >=10x vector-over-scalar on a smaller request count), then validate both the freshly produced record and
     (when present) the committed BENCH_gateway.json against the schema
     -- including the shed-rate fields, the alert-before-migrate
     ordering, the <10% overhead gate, the drift seq ordering, the
-    contention ratios and the recorded scale / disagg speedups."""
+    contention ratios and the recorded scale speedup."""
     pred = _make_predictor("small", WIDTHS["small"])
     bench: dict = {"schema": BENCH_SCHEMA, "scenarios": {}}
     _overload_shed_scenario(pred, bench)
@@ -1389,14 +1230,13 @@ def smoke() -> None:
     _drift_scenario(pred, bench)
     _contention_scenario(pred, bench)
     _scale_scenario(bench, smoke=True)
-    _disagg_scenario(bench, smoke=True)
     validate_bench(bench, require=("overload", "observability", "drift",
-                                   "contention", "scale", "disagg"))
+                                   "contention", "scale"))
     if BENCH_JSON.exists():
         validate_bench(json.loads(BENCH_JSON.read_text()),
                        require=("fleet", "slo_failover", "split_cost",
                                 "overload", "observability", "drift",
-                                "contention", "scale", "disagg"))
+                                "contention", "scale"))
         print(f"validated {BENCH_JSON}", file=sys.stderr)
     print("overload race:",
           json.dumps(bench["scenarios"]["overload"]["race"]),

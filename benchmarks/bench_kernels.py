@@ -1,18 +1,12 @@
 """Kernel microbench: jnp reference path wall time per call on this host
 (the TPU kernels are validated in interpret mode by tests/; wall numbers
-here are the CPU reference path, 'derived' reports achieved GFLOP/s).
-
-Also carries the serving-layer prompt-ingest race (ISSUE 8): tokens/s
-ingesting a P-token prompt through the chunked batched prefill path vs
-the 1-token-per-step teacher-forced reference, at P in {128, 512, 2048}
-(P=128 only under --smoke)."""
+here are the CPU reference path, 'derived' reports achieved GFLOP/s)."""
 from __future__ import annotations
 
 import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import ops
 
@@ -26,57 +20,7 @@ def _time(fn, *args, reps=5):
     return (time.perf_counter() - t0) / reps
 
 
-def _prefill_rows(smoke: bool = False) -> list[dict]:
-    """Prompt-ingest tokens/s: chunked batched prefill vs teacher forcing.
-
-    One request, one slot -- this isolates the per-token ingest cost from
-    the batcher's slot scheduling (the batched race with a full slot pool
-    is bench_gateway's disagg scenario).  Both sides drain the same prompt
-    on the same host after a warmup drain that compiles both phase shapes;
-    outputs are asserted identical before the timed leg."""
-    from repro.configs import registry
-    from repro.models import lm
-    from repro.serving.continuous import ContinuousBatcher
-
-    cfg = registry.get_smoke_config("h2o_danube_3_4b")
-    params = lm.init_params(jax.random.PRNGKey(0), cfg)
-    rng = np.random.default_rng(0)
-    G, chunk = 4, 32
-    rows = []
-    for P in (128,) if smoke else (128, 512, 2048):
-        prompt = rng.integers(1, cfg.vocab_size, P).tolist()
-
-        def drain(b: ContinuousBatcher) -> tuple[list[int], float]:
-            req = b.submit(list(prompt), G)
-            t0 = time.perf_counter()
-            b.run()
-            return req.output, time.perf_counter() - t0
-
-        per = {}
-        for pc in (0, chunk):
-            # one batcher per side: its jitted phase programs compile on
-            # the warmup drain and stay cached for the timed reps
-            b = ContinuousBatcher(cfg, params, max_slots=1,
-                                  max_len=P + G + 4, prefill_chunk=pc)
-            out_w, _ = drain(b)                   # warmup / compile
-            out_t, wall = min((drain(b) for _ in range(2)),
-                              key=lambda r: r[1])
-            assert out_w == out_t, "prefill microbench: nondeterministic"
-            per[pc] = {"out": out_t, "tok_s": P / wall}
-        assert per[0]["out"] == per[chunk]["out"], \
-            f"prefill oracle diverged at P={P}"
-        rows.append({
-            "name": f"serving_prefill_p{P}",
-            "us_per_call": P / per[chunk]["tok_s"] * 1e6,
-            "derived": f"prefill_tok_s={per[chunk]['tok_s']:.0f};"
-                       f"teacher_tok_s={per[0]['tok_s']:.0f};"
-                       f"speedup={per[chunk]['tok_s'] / per[0]['tok_s']:.2f}x;"
-                       f"chunk={chunk}",
-        })
-    return rows
-
-
-def run(smoke: bool = False) -> list[dict]:
+def run() -> list[dict]:
     k = jax.random.PRNGKey(0)
     ks = jax.random.split(k, 6)
     rows = []
@@ -126,15 +70,9 @@ def run(smoke: bool = False) -> list[dict]:
     t = _time(fn, q2, k2, v2, li, lf)
     rows.append({"name": "kernel_mlstm_scan_ref", "us_per_call": t * 1e6,
                  "derived": f"tokens_per_s={B * S / t:.0f}"})
-    rows.extend(_prefill_rows(smoke=smoke))
     return rows
 
 
 if __name__ == "__main__":
-    import argparse
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--smoke", action="store_true",
-                    help="P=128 prefill row only (CI tier)")
-    args = ap.parse_args()
-    for row in run(smoke=args.smoke):
+    for row in run():
         print(f"{row['name']},{row['us_per_call']:.3f},{row['derived']}")

@@ -360,7 +360,12 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, positions, cache):
 
 
 def init_cache(cfg: ArchConfig, batch: int, length: int) -> Any:
-    """Zeroed decode caches (structure mirrors forward(collect_cache))."""
+    """Zeroed decode caches (structure mirrors forward(collect_cache)).
+
+    Every leaf under a ``phase*`` key is (G, batch, ...): the phase's layer
+    groups, then one row per sequence.  ContinuousBatcher relies on this to
+    write one slot's row of every phase leaf; only ``enc_len`` lies outside
+    the phases."""
     plan = blocks.build_plan(cfg)
     cdt = cfg.compute_dtype
     cache: dict = {}

@@ -6,19 +6,16 @@ pool over ONE shared KV cache and admits queued prompts into freed slots
 between steps -- the vLLM-style scheduling pattern, built on the same
 models.lm decode path used by the dry-run (per-sequence positions).
 
-Mechanics: every step advances ALL slots by one token through
-lm.decode_step.  With ``prefill_chunk=0`` (the teacher-forced reference
-path) a newly admitted prompt is "caught up" by teacher-forcing its prompt
-tokens through the decode path (one per step) before switching to
-generation -- a P-token prompt costs P full decode steps across the entire
-slot pool.  With ``prefill_chunk=C`` the prompt instead runs through the
-batched prefill path (lm.prefill_chunk -> the flash-attention style masked
-chunk attention) in O(P/C) calls on a standalone one-row cache, the KV rows
-are scattered into the slot's cache row, and the sequence enters the decode
-pool with its first generated token already emitted.  The oracle suite
-(tests/test_prefill_oracle.py) pins the two paths to each other.  Idle
-slots process a pad token whose writes land in their own cache rows, never
-leaking across slots (cache rows are per-sequence).
+Mechanics: a newly admitted prompt runs through the batched prefill path
+(lm.prefill_chunk -> the flash-attention style masked chunk attention),
+``prefill_chunk`` tokens a call, on a standalone one-row cache; its rows are
+written into the slot's cache row, and the sequence enters the decode pool
+with its first generated token already emitted.  Every step then advances
+ALL slots by one token through lm.decode_step, each busy slot fed its last
+generated token.  The oracle suite (tests/test_prefill_oracle.py) holds the
+prefill to the teacher-forced decode_step reference.  Idle slots process a
+pad token whose writes land in their own cache rows, never leaking across
+slots (cache rows are per-sequence).
 """
 from __future__ import annotations
 
@@ -60,12 +57,10 @@ def _emit(req: Request, token: int):
         req.t_first = time.perf_counter()
 
 
-def _on_slot_rows(cache, max_slots: int, f, *rows):
-    """f(leaf, *row leaves) on each phase-cache leaf that holds a row per
-    slot (axis 1 of size max_slots); every other leaf as it is."""
-    def each(a, *r):
-        return f(a, *r) if a.ndim >= 2 and a.shape[1] == max_slots else a
-    return {k: (jax.tree_util.tree_map(each, v, *(r[k] for r in rows))
+def _on_slot_rows(cache, f, *rows):
+    """f(leaf, *row leaves) on every phase-cache leaf, each (G, slots, ...)
+    by lm.init_cache's contract; every other leaf as it is."""
+    return {k: (jax.tree_util.tree_map(f, v, *(r[k] for r in rows))
                 if k.startswith("phase") else v)
             for k, v in cache.items()}
 
@@ -83,14 +78,16 @@ def decode_program(cfg: ArchConfig):
 class _Slot:
     req: Optional[Request] = None
     pos: int = 0              # next cache position for this row
-    remaining_prompt: int = 0  # tokens still being teacher-forced
 
 
 class ContinuousBatcher:
     def __init__(self, cfg: ArchConfig, params, *, max_slots: int = 4,
                  max_len: int = 128, eos_id: Optional[int] = None,
-                 prefill_chunk: int = 0):
+                 prefill_chunk: int = 256):
         assert cfg.family not in ("audio",), "enc-dec admission not supported"
+        if prefill_chunk < 1:
+            raise ValueError(f"prefill_chunk {prefill_chunk} < 1: a prompt is "
+                             "admitted by chunked prefill")
         self.cfg = cfg
         self.params = params
         self.max_slots = max_slots
@@ -112,28 +109,27 @@ class ContinuousBatcher:
         self._decode = decode_program(cfg)
 
         def zero_row(c, i):
-            return _on_slot_rows(c, max_slots, lambda a: a.at[:, i].set(0))
+            return _on_slot_rows(c, lambda a: a.at[:, i].set(0))
 
         def put_row(c, row, i):
             return _on_slot_rows(
-                c, max_slots, lambda a, r: a.at[:, i].set(r[:, 0].astype(a.dtype)),
-                row)
+                c, lambda a, r: a.at[:, i].set(r[:, 0].astype(a.dtype)), row)
 
         # admission writes one slot's row in place, as the decode step does
         self._zero_row = jax.jit(zero_row, donate_argnums=0)
         self._put_row = jax.jit(put_row, donate_argnums=0)
-        if self.prefill_chunk > 0:
-            self._prefill = jax.jit(serve_prefill)
-            self._row_cache_zeros = lm.init_cache(cfg, 1, max_len)
-            # per-phase counters the disaggregated cost model reads
-            self.prefill_stats = {"requests": 0, "chunks": 0, "tokens": 0}
+        self._prefill = jax.jit(serve_prefill)
+        self._row_cache_zeros = lm.init_cache(cfg, 1, max_len)
+        # per-phase counters the two-phase cost model reads
+        self.prefill_stats = {"requests": 0, "chunks": 0, "tokens": 0}
 
     # -- client API ---------------------------------------------------------
     def submit(self, prompt: list, max_new: int) -> Request:
-        # A prompt must leave room for at least one generated token: the
-        # done-check fires at pos >= max_len - 1 only once output exists, so
-        # an unbounded prompt used to walk pos past the cache bound with its
-        # KV writes silently dropped (out-of-range scatter) -- reject here.
+        if not prompt:
+            raise ValueError("empty prompt: nothing to prefill")
+        # A prompt must leave room for at least one generated token: an
+        # unbounded prompt used to walk pos past the cache bound with its KV
+        # writes silently dropped (out-of-range scatter) -- reject here.
         if len(prompt) >= self.max_len:
             raise ValueError(
                 f"prompt length {len(prompt)} >= max_len {self.max_len}: "
@@ -169,23 +165,16 @@ class ContinuousBatcher:
                     req.t_admit = time.perf_counter()
                     s.req = req
                     self._reset_row(i)
-                    if self.prefill_chunk > 0 and req.prompt:
-                        _emit(req, self._prefill_into(i, req))
-                        s.pos = len(req.prompt)
-                        s.remaining_prompt = 0
-                        self._maybe_finish(s)
-                    else:
-                        s.pos = 0
-                        s.remaining_prompt = len(req.prompt)
+                    _emit(req, self._prefill_into(i, req))
+                    s.pos = len(req.prompt)
+                    self._maybe_finish(s)
 
     def _maybe_finish(self, s: _Slot):
         """Free the slot if its request is complete: max_new tokens, eos,
         or the end of the cache row."""
         req = s.req
-        hit_eos = self.eos_id is not None and req.output \
-            and req.output[-1] == self.eos_id
-        if req.output and (len(req.output) >= req.max_new or hit_eos
-                           or s.pos >= self.max_len - 1):
+        hit_eos = self.eos_id is not None and req.output[-1] == self.eos_id
+        if len(req.output) >= req.max_new or hit_eos or s.pos >= self.max_len - 1:
             req.done = True
             req.finished_step = self.step_count
             req.t_done = time.perf_counter()
@@ -248,16 +237,7 @@ class ContinuousBatcher:
         """Each slot's next token and position, on the device."""
         tokens, positions = [], []
         for s in self.slots:
-            if s.req is None:
-                tokens.append(PAD)
-                positions.append(s.pos)
-                continue
-            if s.remaining_prompt > 0:     # teacher-force the prompt
-                idx = len(s.req.prompt) - s.remaining_prompt
-                tokens.append(s.req.prompt[idx])
-            else:                          # feed back last generated token
-                tokens.append(s.req.output[-1] if s.req.output
-                              else s.req.prompt[-1])
+            tokens.append(PAD if s.req is None else s.req.output[-1])
             positions.append(s.pos)
         tok = jnp.asarray(tokens, jnp.int32)[:, None]
         pos = jnp.asarray(positions, jnp.int32)
@@ -271,12 +251,7 @@ class ContinuousBatcher:
             if s.req is None:
                 continue
             s.pos += 1
-            if s.remaining_prompt > 0:
-                s.remaining_prompt -= 1
-                if s.remaining_prompt == 0:
-                    _emit(s.req, int(nxt[i]))   # first generated token
-            else:
-                _emit(s.req, int(nxt[i]))
+            _emit(s.req, int(nxt[i]))
             self._maybe_finish(s)
 
     def run(self, max_steps: int = 10_000) -> list:

@@ -371,23 +371,17 @@ class Predictor:
 class BatcherBackend:
     """Adapt a ContinuousBatcher (serving/continuous.py) as a router backend.
 
-    An LLM's unit of work is decode steps, not one jitted call: a request
-    costs ``prompt_len + gen_tokens`` steps (teacher-forced catch-up, then
-    generation), and b concurrent requests run in ``ceil(b / max_slots)``
-    slot waves.  Per-step wall time is measured once by draining a real
-    request through the batcher (after a jit warmup drain), keeping the
-    compute term hardware-true like Predictor.service_time.
-
-    With a disaggregated batcher (``prefill_chunk > 0``) the blended
-    per-step estimate splits into two MEASURED cost models (ISSUE 8):
-    ``prefill_time(P)`` -- ceil(P / chunk) prefill-kernel calls at the
-    measured per-chunk latency (prompt ingest is serial per request, it
-    runs on a one-row cache at admission) -- and ``decode_time(steps)`` --
-    per-step decode latency times steps, shared by every occupied slot.
-    The two phases are separated by timing two workload points (a
-    prompt_len-token prompt and a 1-token prompt, both generating
-    gen_tokens) and solving the resulting 2x2 system in (chunk, step)
-    counts read back from the batcher's own phase counters.
+    An LLM's unit of work is not one jitted call.  A request costs
+    ``prefill_time(P)`` -- ceil(P / chunk) prefill calls at the measured
+    per-chunk latency (prompt ingest is serial per request, it runs on a
+    one-row cache at admission) -- plus ``decode_time(steps)`` -- per-step
+    decode latency times steps, shared by every occupied slot; b concurrent
+    requests decode in ``ceil(b / max_slots)`` slot waves.  Both costs are
+    MEASURED on the real batcher (after a jit warmup drain), keeping the
+    compute term hardware-true like Predictor.service_time: two workload
+    points (a prompt_len-token prompt and a 1-token prompt, both generating
+    gen_tokens) are timed and the resulting 2x2 system is solved in
+    (chunk, step) counts read back from the batcher's own counters.
     """
 
     def __init__(self, name: str, batcher, *, prompt_len: int = 8,
@@ -399,31 +393,20 @@ class BatcherBackend:
         self._step_time: Optional[float] = None
         self._chunk_time: Optional[float] = None
 
-    @property
-    def disaggregated(self) -> bool:
-        return getattr(self.batcher, "prefill_chunk", 0) > 0
-
     def _timed_run(self, prompt: list) -> tuple:
         """One timed submit+drain; returns (wall_s, chunks, steps) deltas."""
         b = self.batcher
-        c0 = b.prefill_stats["chunks"] if self.disaggregated else 0
-        s0 = b.step_count
+        c0, s0 = b.prefill_stats["chunks"], b.step_count
         b.submit(prompt, self.gen_tokens)
         t0 = time.perf_counter()
         b.run()
         dt = time.perf_counter() - t0
-        c1 = b.prefill_stats["chunks"] if self.disaggregated else 0
-        return dt, c1 - c0, b.step_count - s0
+        return dt, b.prefill_stats["chunks"] - c0, b.step_count - s0
 
     def _measure(self) -> None:
         prompt = [1 + (i % 97) for i in range(self.prompt_len)]
         self.batcher.submit(prompt, self.gen_tokens)
         self.batcher.run()                       # warmup: jit compile
-        if not self.disaggregated:
-            dt, _, steps = self._timed_run(prompt)
-            self._step_time = dt / max(steps, 1)
-            self._chunk_time = self._step_time
-            return
         self.batcher.submit([1], self.gen_tokens)
         self.batcher.run()                       # warm the short-chunk shape
         dt_a, ch_a, st_a = self._timed_run(prompt)
@@ -443,14 +426,10 @@ class BatcherBackend:
 
     def prefill_time(self, prompt_tokens: Optional[int] = None) -> float:
         """Measured prompt-ingest cost for ONE request: ceil(P / chunk)
-        prefill calls when disaggregated, P teacher-forced decode steps
-        otherwise."""
+        prefill calls."""
         self._ensure_measured()
         p = self.prompt_len if prompt_tokens is None else int(prompt_tokens)
-        if not self.disaggregated:
-            return p * self._step_time
-        chunk = max(self.batcher.prefill_chunk, 1)
-        return math.ceil(p / chunk) * self._chunk_time
+        return math.ceil(p / self.batcher.prefill_chunk) * self._chunk_time
 
     def decode_time(self, steps: Optional[int] = None) -> float:
         """Measured generation cost: per-step decode latency x steps
@@ -460,13 +439,9 @@ class BatcherBackend:
         return n * self._step_time
 
     def service_time(self, b: int) -> float:
-        self._ensure_measured()
-        waves = math.ceil(b / self.batcher.max_slots)
-        if not self.disaggregated:
-            return waves * (self.prompt_len + self.gen_tokens) \
-                * self._step_time
         # prompt ingest is serial per request (one-row prefill cache at
         # admission); generation advances whole slot waves per step
+        waves = math.ceil(b / self.batcher.max_slots)
         return (b * self.prefill_time(self.prompt_len)
                 + waves * self.decode_time(self.gen_tokens))
 

@@ -119,8 +119,10 @@ def test_max_steps_bounds_each_run_call(setup):
     draining on later run() calls, not die at a lifetime step cap."""
     cfg, params = setup
     cb = ContinuousBatcher(cfg, params, max_slots=1, max_len=64)
-    r = cb.submit([1, 2, 3], max_new=4)          # needs 7 steps total
+    # the first token comes at admission, the other 7 one a step
+    r = cb.submit([1, 2, 3], max_new=8)
     assert cb.run(max_steps=4) == []             # budget exhausted mid-flight
+    assert len(r.output) == 5
     done = cb.run(max_steps=50)                  # fresh budget resumes
     assert [x.rid for x in done] == [r.rid] and r.done
 
@@ -150,10 +152,11 @@ def test_admission_is_fifo_under_backlog(setup):
                for r in reqs)
 
 
-@pytest.mark.parametrize("prefill_chunk", [0, 4])
+@pytest.mark.parametrize("prefill_chunk", [1, 4])
 def test_requests_carry_their_clock_times(setup, prefill_chunk):
-    """t_submit <= t_admit <= t_first <= t_done on both admission paths;
-    under backlog the last request waits in the queue longest."""
+    """t_submit <= t_admit <= t_first <= t_done, whether the prompt takes
+    one prefill call or several; under backlog the last request waits in
+    the queue longest."""
     cfg, params = setup
     cb = ContinuousBatcher(cfg, params, max_slots=2, max_len=64,
                            prefill_chunk=prefill_chunk)
@@ -164,6 +167,23 @@ def test_requests_carry_their_clock_times(setup, prefill_chunk):
         assert r.t_submit <= r.t_admit <= r.t_first <= r.t_done
     waits = [r.t_admit - r.t_submit for r in reqs]
     assert waits[-1] == max(waits)
+
+
+@pytest.mark.parametrize("bad", ["empty_prompt", "prefill_chunk_0"])
+def test_inputs_that_cannot_be_admitted_are_refused(setup, bad):
+    """An empty prompt has nothing to prefill (it used to raise IndexError
+    at the first step), and a prompt is admitted only by chunked prefill,
+    so a chunk below one token is refused when the batcher is built."""
+    cfg, params = setup
+    if bad == "prefill_chunk_0":
+        with pytest.raises(ValueError, match="prefill_chunk"):
+            ContinuousBatcher(cfg, params, max_slots=1, max_len=64,
+                              prefill_chunk=0)
+    else:
+        cb = ContinuousBatcher(cfg, params, max_slots=1, max_len=64)
+        with pytest.raises(ValueError, match="empty prompt"):
+            cb.submit([], max_new=2)
+        assert not cb.queue and not cb.requests
 
 
 def _scan_ys_decode_step(params, cfg, tokens, positions, cache):

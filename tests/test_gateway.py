@@ -553,12 +553,15 @@ def test_batcher_backend_service_time_and_generation():
 
     cfg = registry.get_smoke_config("h2o_danube_3_4b")
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
-    cb = ContinuousBatcher(cfg, params, max_slots=2, max_len=64)
+    cb = ContinuousBatcher(cfg, params, max_slots=2, max_len=64,
+                           prefill_chunk=2)
     be = BatcherBackend("llm", cb, prompt_len=4, gen_tokens=3)
-    t1 = be.service_time(2)          # one slot wave
-    t2 = be.service_time(3)          # two waves
-    assert t1 > 0
-    assert abs(t2 / t1 - 2.0) < 1e-6
+    pf, dec = be.prefill_time(), be.decode_time()
+    assert pf > 0 and dec > 0
+    # prompts ingest one by one; b requests decode in ceil(b / 2) waves
+    for b in (1, 2, 3):
+        assert be.service_time(b) == pytest.approx(
+            b * pf + math.ceil(b / 2) * dec)
     outs = be.generate([[5, 17, 99], [7, 7]], max_new=3)
     assert len(outs) == 2 and all(len(o) == 3 for o in outs)
 

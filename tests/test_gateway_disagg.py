@@ -214,35 +214,14 @@ def test_batcher_backend_cost_split():
     b = ContinuousBatcher(cfg, params, max_slots=2, max_len=64,
                           prefill_chunk=8)
     be = BatcherBackend("llm", b, prompt_len=16, gen_tokens=4)
-    assert be.disaggregated
     pf, dec = be.prefill_time(16), be.decode_time(4)
     assert pf > 0 and dec > 0
-    # chunked ingest of a 16-token prompt is 2 prefill calls; the
-    # teacher-forced equivalent would be 16 decode steps
+    # chunked ingest of a 16-token prompt is 2 prefill calls
     assert be.prefill_time(16) == 2 * be.prefill_time(8)
     assert be.decode_time(8) == 2 * be.decode_time(4)
     assert be.service_time(1) == pytest.approx(pf + dec)
     assert be.service_time(2) == pytest.approx(2 * pf + dec)
     assert be.service_time(3) == pytest.approx(3 * pf + 2 * dec)
-
-
-def test_batcher_backend_blended_fallback():
-    class FakeBatcher:
-        prefill_chunk = 0
-        max_slots = 2
-        step_count = 0
-
-        def submit(self, prompt, max_new):
-            self._work = len(prompt) + max_new
-
-        def run(self):
-            self.step_count += self._work
-            return []
-
-    be = BatcherBackend("m", FakeBatcher(), prompt_len=8, gen_tokens=4)
-    assert not be.disaggregated
-    # blended: prefill is priced as P teacher-forced steps
-    assert be.prefill_time(8) == pytest.approx(8 * be.decode_time(1))
 
 
 # -- placement demand split --------------------------------------------------
